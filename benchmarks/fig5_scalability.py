@@ -103,7 +103,8 @@ def run(batch_tuples: int = 262144, full: bool = False):
     # across devices (requires a multi-device host; skipped on 1 device)
     if len(jax.devices()) > 1:
         n_dev = len(jax.devices())
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((n_dev, 1), ("data", "model"))
         eng = SDE(mesh=mesh)
         eng.handle({"type": "build", "request_id": "b", "synopsis_id":
                     "cm", "kind": "countmin",
@@ -214,51 +215,6 @@ def run(batch_tuples: int = 262144, full: bool = False):
         "fig5f_pipelined_speedup", 0.0,
         f"speedup={thr_by_mode['pipelined'] / thr_by_mode['eager']:.2f}x "
         "(acceptance >= 1.2x)"))
-
-    # ---------------- (g) fused vs probe-then-scatter Pallas path ------
-    # 1024 routed synopses on backend="pallas", same registry kernel both
-    # ways; SDE_FUSED_PROBE flips whether the routing probe runs INSIDE
-    # the Pallas grid (one HBM pass over state+table per batch) or as a
-    # separate jnp probe ahead of the delta-buffer kernel. Wall clock here
-    # is interpret-mode off-TPU (both modes pay the interpreter), so the
-    # measured ratio is indicative; the HBM-byte acceptance (>= 1.2x
-    # modeled gain at 1024 synopses) is gated by `roofline.py --check`.
-    import os as _os
-    n_syn_g = 1024
-    g_stock = StockStream(n_streams=n_syn_g, seed=4)
-    g_batches = [g_stock.level1_batch(4096) for _ in range(4)]
-    g_build = {"type": "build", "request_id": "b", "synopsis_id": "cm",
-               "kind": "countmin",
-               "params": {"eps": 0.2, "delta": 0.3, "weighted": False},
-               "per_stream_of_source": True, "n_streams": n_syn_g}
-    t_by_fuse = {}
-    for fuse in ("0", "1"):
-        _os.environ["SDE_FUSED_PROBE"] = fuse
-        try:
-            def run_once():
-                eng = SDE(backend="pallas")
-                assert eng.handle(g_build).ok
-                eng.ingest(*g_batches[0])    # warmup: trace + compile
-                jax.block_until_ready(
-                    [s.state for s in eng.stacks.values()])
-                t0 = _time.perf_counter()
-                for sids, vals in g_batches:
-                    eng.ingest(sids, vals)
-                jax.block_until_ready(
-                    [s.state for s in eng.stacks.values()])
-                return _time.perf_counter() - t0
-            t = float(np.median([run_once() for _ in range(2)]))
-        finally:
-            _os.environ.pop("SDE_FUSED_PROBE", None)
-        t_by_fuse[fuse] = t
-        label = "fused" if fuse == "1" else "probe_then_scatter"
-        thr = len(g_batches) * len(g_batches[0][0]) / t
-        rows.append(csv_row(f"fig5g_{label}_{n_syn_g}syn", t,
-                            f"throughput={thr:,.0f}tuples/s"))
-    rows.append(csv_row(
-        "fig5g_fused_speedup", 0.0,
-        f"speedup={t_by_fuse['0'] / t_by_fuse['1']:.2f}x wall "
-        "(interpret-mode; HBM-byte gate: roofline.py --check)"))
 
     # ---------------- (d) federated communication ----------------
     # Per 5-minute ad-hoc query (paper setting), three ways of answering

@@ -1,26 +1,14 @@
 """Aggregate dry-run records into the roofline table (EXPERIMENTS.md
-§Roofline reads this output), and gate the fused Pallas update kernels.
+§Roofline reads this output), and gate the Pallas update kernels.
 
   PYTHONPATH=src python -m benchmarks.roofline --dir benchmarks/out
   PYTHONPATH=src python -m benchmarks.roofline --check [--shape full]
 
 ``--check`` runs every registered update kernel (kernels.ops registry)
-fused and unfused in interpret mode, proves the Pallas states are
-byte-identical to the XLA reference path, and models the HBM bytes each
-form moves per batch:
-
-  fused     = R+W state (aliased) + R table mirror + R batch
-  unfused   = probe pass (R table + R sids, W rows) + kernel pass
-              (R rows + R batch + state traffic) — where the CM/AMS
-              delta-buffer form pays 4 state-sized passes (W delta,
-              R delta, R counts, W out) against the fused form's 2.
-
-Per-kernel thresholds: the delta-buffer kinds (countmin_scatter,
-ams_scatter) must model >= 1.2x; the already-aliased single-pass kinds
-(hll_max, bloom_bitset, fm_bitmap, rhp_project) must not regress
-(>= 1.0x) and are gated on byte equality. Records land next to the
-dry-run records with ``mesh_name="cpu-interpret"`` so ``table()`` can
-filter them the same way.
+through ``SDE(backend="pallas")`` and proves its state byte-identical to
+the XLA reference path (``backend="xla"``) on the same batch. Records
+land next to the dry-run records with ``mesh_name`` set to the backend
+the kernels ran on, so ``table()`` can filter them the same way.
 """
 from __future__ import annotations
 
@@ -32,22 +20,16 @@ import sys
 import time
 from typing import Dict, List
 
-# kernel-gate cases: (registry kernel, api kind, params, min modeled gain)
+# kernel-gate cases: (registry kernel, api kind, params)
 _GATE = [
     ("countmin_scatter", "countmin",
-     {"eps": 0.1, "delta": 0.1, "weighted": False}, 1.2),
-    ("ams_scatter", "ams", {"eps": 0.1, "delta": 0.1}, 1.2),
-    ("hll_max", "hyperloglog", {"rse": 0.1}, 1.0),
-    ("bloom_bitset", "bloom", {"n_elements": 64, "fpr": 0.05}, 1.0),
-    ("fm_bitmap", "fm", {}, 1.0),
-    ("rhp_project", "rhp", {"n_bits": 64}, 1.0),
+     {"eps": 0.1, "delta": 0.1, "weighted": False}),
+    ("ams_scatter", "ams", {"eps": 0.1, "delta": 0.1}),
+    ("hll_max", "hyperloglog", {"rse": 0.1}),
+    ("bloom_bitset", "bloom", {"n_elements": 64, "fpr": 0.05}),
+    ("fm_bitmap", "fm", {}),
+    ("rhp_project", "rhp", {"n_bits": 64}),
 ]
-_DELTA_KINDS = ("countmin_scatter", "ams_scatter")
-# per-tuple batch bytes: hashed sid halves (2 x u32) + value f32 + mask
-_TUPLE_B = 13
-# per-tuple probe-path extra: R sids in probe (8) + W rows (4) + R rows
-# in the scatter kernel (4) — the bytes fusion deletes
-_PROBE_B = 16
 
 
 def load(dir_: str) -> List[Dict]:
@@ -117,19 +99,17 @@ def summary(records: List[Dict]) -> Dict:
 
 
 # ---------------------------------------------------------------------------
-# fused-kernel acceptance gate (--check)
+# update-kernel acceptance gate (--check)
 # ---------------------------------------------------------------------------
 def kernel_records(shape: str = "gate") -> List[Dict]:
-    """One record per registry kernel: byte equality of the Pallas
-    states (fused AND unfused) against the XLA reference engine, plus
-    the modeled HBM traffic of each form at the gate shape."""
+    """One record per registry kernel: byte equality of the Pallas state
+    against the XLA reference engine on the same batch."""
     import numpy as np
     import jax
     from repro.service import SDE
 
     # gate shapes keep the interpret-mode grids tiny (CI runs this on
-    # CPU); --shape full scales to the 1024-synopsis acceptance point,
-    # where the modeled gain is state-dominated
+    # CPU); --shape full scales to the 1024-synopsis point
     full = shape == "full"
     n_syn = 1024 if full else 16
     t_tuples = 4096 if full else 512
@@ -139,57 +119,36 @@ def kernel_records(shape: str = "gate") -> List[Dict]:
                                 dtype=np.int64))[:n_syn]
     sids = pop[rng.randint(0, n_syn, t_tuples)]
     # sprinkle ids outside the routed population — the probe's miss
-    # (-1) path must round-trip through every kernel form too
+    # (-1) path must round-trip through every kernel too
     sids[::max(t_tuples // 16, 1)] = int(pop.max()) + 1
     vals = rng.randint(1, 5, t_tuples).astype(np.float32)
 
     records = []
-    for kernel, kind_name, params, min_gain in _GATE:
-        states, wall, s_bytes, tbl_bytes = {}, None, None, None
-        for backend, fuse in (("xla", "1"), ("pallas", "0"),
-                              ("pallas", "1")):
-            os.environ["SDE_FUSED_PROBE"] = fuse
-            try:
-                eng = SDE(backend=backend)
-                r = eng.handle({
-                    "type": "build", "request_id": "b",
-                    "synopsis_id": "g", "kind": kind_name,
-                    "params": params, "per_stream_of_source": True,
-                    "stream_ids": [int(s) for s in pop]})
-                assert r.ok, r.error
-                t0 = time.perf_counter()
-                eng.ingest(sids, vals)
-                jax.block_until_ready(
-                    [s.state for s in eng.stacks.values()])
-                dt = time.perf_counter() - t0
-            finally:
-                os.environ.pop("SDE_FUSED_PROBE", None)
-            stack = next(iter(eng.stacks.values()))
-            states[(backend, fuse)] = np.asarray(stack.state)
-            if (backend, fuse) == ("pallas", "1"):
-                wall = dt
-                s_bytes = states[(backend, fuse)].nbytes
-                tbl_bytes = sum(np.asarray(a).nbytes
-                                for a in stack.device_table())
+    for kernel, kind_name, params in _GATE:
+        states, wall = {}, None
+        for backend in ("xla", "pallas"):
+            eng = SDE(backend=backend)
+            r = eng.handle({
+                "type": "build", "request_id": "b",
+                "synopsis_id": "g", "kind": kind_name,
+                "params": params, "per_stream_of_source": True,
+                "stream_ids": [int(s) for s in pop]})
+            assert r.ok, r.error
+            t0 = time.perf_counter()
+            eng.ingest(sids, vals)
+            jax.block_until_ready([s.state for s in eng.stacks.values()])
+            wall = time.perf_counter() - t0
+            states[backend] = np.asarray(next(iter(eng.stacks.values()))
+                                         .state)
             eng.close()
-        byte_equal = (
-            np.array_equal(states[("xla", "1")], states[("pallas", "1")])
-            and np.array_equal(states[("xla", "1")],
-                               states[("pallas", "0")]))
-        state_passes = 4 if kernel in _DELTA_KINDS else 2
-        fused = 2 * s_bytes + tbl_bytes + _TUPLE_B * t_tuples
-        unfused = (state_passes * s_bytes + tbl_bytes
-                   + (_TUPLE_B + _PROBE_B) * t_tuples)
         records.append(dict(
             kernel=kernel, kind=kind_name, shape=shape,
             n_synopses=n_syn, batch_tuples=t_tuples,
-            state_bytes=s_bytes, table_bytes=tbl_bytes,
-            fused_hbm_bytes=fused, unfused_hbm_bytes=unfused,
-            modeled_gain=round(unfused / fused, 3), min_gain=min_gain,
-            byte_equal=bool(byte_equal),
-            wall_seconds_fused=round(wall, 4),
-            backend="pallas", interpret=True,
-            mesh_name="cpu-interpret"))
+            state_bytes=states["pallas"].nbytes,
+            byte_equal=bool(np.array_equal(states["xla"],
+                                           states["pallas"])),
+            wall_seconds_pallas_incl_compile=round(wall, 4),
+            backend="pallas", mesh_name=jax.default_backend()))
     return records
 
 
@@ -197,22 +156,16 @@ def check(records: List[Dict], out_dir: str = None) -> List[str]:
     """Print the gate table, persist the records, return failures."""
     failures = []
     lines = [
-        "| kernel | n_syn | batch | modeled gain | min | bytes | ok |",
-        "|---|---|---|---|---|---|---|",
+        "| kernel | n_syn | batch | bytes | ok |",
+        "|---|---|---|---|---|",
     ]
     for r in records:
-        ok = r["byte_equal"] and r["modeled_gain"] >= r["min_gain"]
         if not r["byte_equal"]:
             failures.append(f"{r['kernel']}: pallas != xla state bytes")
-        elif not ok:
-            failures.append(
-                f"{r['kernel']}: modeled gain {r['modeled_gain']}x "
-                f"< required {r['min_gain']}x")
         lines.append(
             f"| {r['kernel']} | {r['n_synopses']} | {r['batch_tuples']} "
-            f"| {r['modeled_gain']:.2f}x | {r['min_gain']:.1f}x "
             f"| {'equal' if r['byte_equal'] else 'DIFFER'} "
-            f"| {'PASS' if ok else 'FAIL'} |")
+            f"| {'PASS' if r['byte_equal'] else 'FAIL'} |")
     print("\n".join(lines))
     if out_dir:
         os.makedirs(out_dir, exist_ok=True)
@@ -230,7 +183,7 @@ def main():
     ap.add_argument("--dir", default="benchmarks/out")
     ap.add_argument("--mesh", default="pod16x16")
     ap.add_argument("--check", action="store_true",
-                    help="run the fused-kernel acceptance gate")
+                    help="run the update-kernel acceptance gate")
     ap.add_argument("--shape", default="gate", choices=["gate", "full"],
                     help="--check problem size (full = 1024 synopses)")
     args = ap.parse_args()

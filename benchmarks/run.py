@@ -13,6 +13,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+import traceback
 
 
 def main(argv=None) -> None:
@@ -38,7 +39,9 @@ def main(argv=None) -> None:
                 fig13=fig13_subpop)
     only = set(args.only.split(",")) if args.only else set(figs)
 
-    results = []
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
+    results, failed = [], []
     print("name,us_per_call,derived")
     for name, mod in figs.items():
         if name not in only:
@@ -51,14 +54,16 @@ def main(argv=None) -> None:
                     fig=name, name=cells[0],
                     us_per_call=float(cells[1]),
                     derived=cells[2] if len(cells) > 2 else ""))
-        except Exception as e:  # keep the harness running
-            print(f"{name}_ERROR,0,{e!r}", flush=True)
-            results.append(dict(fig=name, name=f"{name}_ERROR",
-                                us_per_call=0.0, derived=repr(e)))
+        except Exception:  # run the other figures, then fail
+            traceback.print_exc()
+            failed.append(name)
     if args.summary:
         with open(args.summary, "w", encoding="utf-8") as f:
-            json.dump(dict(full=args.full, rows=results), f, indent=2)
+            json.dump(dict(full=args.full, rows=results, failed=failed),
+                      f, indent=2)
             f.write("\n")
+    if failed:
+        sys.exit(f"figures failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -81,14 +81,11 @@ def main():
     #     `update_kernel = "<name>"` resolved from the kernels.ops
     #     registry at dispatch (no isinstance ladder); scan kinds and the
     #     DFT step path fall back to the same XLA programs as backend=
-    #     "xla". By default the routing probe runs INSIDE the kernel grid
-    #     (one HBM pass over state + table per kind per batch;
-    #     SDE_FUSED_PROBE=0 splits it out again), and states stay
-    #     byte-identical to the XLA backend either way — that equivalence
-    #     plus the modeled HBM gain is CI-gated by
-    #     `python -m benchmarks.roofline --check` (EXPERIMENTS.md
-    #     §Roofline). Off-TPU the kernels run in interpret mode
-    #     (override with SDE_PALLAS_INTERPRET=0/1). A plugged kind reuses
+    #     "xla". The routing probe runs ahead of the kernel in the same
+    #     jitted dispatch, and states stay byte-identical to the XLA
+    #     backend — CI-gated by `python -m benchmarks.roofline --check`.
+    #     On a TPU the kernels are compiled by Mosaic; elsewhere they run
+    #     in interpret mode. A plugged kind reuses
     #     a stock kernel by declaring its name, or brings its own via
     #     `kernels.ops.register_update_kernel(name, builder)`.
     ksde = SDE(backend="pallas")
@@ -104,7 +101,7 @@ def main():
     q = ksde.handle({"type": "adhoc", "request_id": "kq",
                      "synopsis_id": "kbids/42", "query": {"items": [42]}})
     print(f"\npallas backend: stock 42 bid volume (CM) "
-          f"{float(q.value[0]):,.1f} via fused probe+update kernel")
+          f"{float(q.value[0]):,.1f} via the Pallas update kernel")
 
     # 2d. Serving many clients: the `SynopsisGateway` front door
     #     multiplexes N concurrent clients onto ONE engine. Per tick it

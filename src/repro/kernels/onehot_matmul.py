@@ -2,20 +2,27 @@
 
 THE central TPU adaptation of the paper's hot loop. CPU/Flink (and GPU)
 update a CountMin/AMS sketch with scatter-adds; TPUs hate scatter but love
-dense matmuls. A block of T updates routed to a stack of sketches becomes
+dense matmuls. A block of T updates routed to a stack of sketches becomes,
+for each hash row j,
 
     counts[syn, j, w] += sum_t (syn_t == syn) * v_t * s_tj * (idx_tj == w)
-                       =        A^T @ B
-    A[t, syn] = (syn_t == syn) * v_t * sign_tj      (one-hot rows, weighted)
-    B[t, w]   = (idx_tj == w)                       (one-hot buckets)
+                       =        A_j @ B_j^T
+    A_j[syn, t] = (syn_t == syn) * v_t * s_tj       (one-hot rows, weighted)
+    B_j[w, t]   = (idx_tj == w)                     (one-hot buckets)
 
-i.e. an [S_tile x T_tile] x [T_tile x W_tile] matmul per grid cell — 100%
-MXU work, zero scatter. The same kernel serves CountMin (sign == 1) and
-AMS/count-sketch (sign == ±1), and the stacked thousands-of-synopses path
-(paper's slot sharing) for free via the `syn` one-hot.
+i.e. an [S_tile x T_tile] x [T_tile x W_tile] matmul per hash row per grid
+cell — MXU work, zero scatter. The same kernel serves CountMin (sign == 1)
+and AMS/count-sketch (sign == ±1), and the stacked thousands-of-synopses
+path (paper's slot sharing) for free via the `syn` one-hot.
 
-Grid: (d, S_tiles, W_tiles, T_tiles); T is innermost so each output tile
-is revisited consecutively and accumulated in VMEM.
+Layout (Mosaic's (8, 128) block rule): per-tuple operands ride with T on
+the lane axis — ``syn``/``values`` as [1, T], ``idx``/``signs`` as [d, T]
+— and the state block spans the whole hash-row axis, (S_tile, d, W_tile).
+Grid: (S_tiles, W_tiles, T_tiles); T is innermost so each output tile is
+revisited consecutively and accumulated in VMEM, starting from the state
+block (the state operand is aliased to the output: in place). The matmul
+runs at ``Precision.HIGHEST`` so weighted f32 updates do not round through
+bf16 — integer-valued weights stay byte-equal to the XLA scatter.
 """
 from __future__ import annotations
 
@@ -24,36 +31,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from . import probe
 
 
-def _kernel(syn_ref, idx_ref, val_ref, sgn_ref, out_ref, *, s_tile, w_tile):
-    t = pl.program_id(3)
-    s_base = pl.program_id(1) * s_tile
-    w_base = pl.program_id(2) * w_tile
+def _kernel(syn_ref, idx_ref, val_ref, sgn_ref, cnt_ref, out_ref, *,
+            s_tile, w_tile, t_tile, depth):
+    s = pl.program_id(0)
+    w_ = pl.program_id(1)
+    t = pl.program_id(2)
 
-    syn = syn_ref[...]                      # [T_t]
-    idx = idx_ref[..., 0]                   # [T_t]   (this j's buckets)
-    val = val_ref[...] * sgn_ref[..., 0]    # [T_t]   (sign folded in)
-
-    s_ids = s_base + jax.lax.broadcasted_iota(jnp.int32, (1, s_tile), 1)
-    w_ids = w_base + jax.lax.broadcasted_iota(jnp.int32, (1, w_tile), 1)
-
-    a = jnp.where(syn[:, None] == s_ids, val[:, None], 0.0)      # [T_t, S_t]
-    b = (idx[:, None] == w_ids).astype(jnp.float32)              # [T_t, W_t]
-    tile = jax.lax.dot_general(
-        a, b, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                      # [S_t, W_t]
+    s_ids = s * s_tile + jax.lax.broadcasted_iota(
+        jnp.int32, (s_tile, t_tile), 0)
+    w_ids = w_ * w_tile + jax.lax.broadcasted_iota(
+        jnp.int32, (w_tile, t_tile), 0)
+    hit = s_ids == syn_ref[...]                          # [S_t, T_t]
+    val = val_ref[...]                                   # [1, T_t]
 
     @pl.when(t == 0)
     def _init():
-        out_ref[...] = tile[:, None, :]
+        out_ref[...] = cnt_ref[...]
 
-    @pl.when(t > 0)
-    def _acc():
-        out_ref[...] += tile[:, None, :]
+    for j in range(depth):
+        a = jnp.where(hit, val * sgn_ref[j:j + 1, :], 0.0)          # [S_t, T_t]
+        b = (w_ids == idx_ref[j:j + 1, :]).astype(jnp.float32)    # [W_t, T_t]
+        out_ref[:, j, :] += jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)                   # [S_t, W_t]
 
 
 @functools.partial(jax.jit, static_argnames=("s_tile", "w_tile", "t_tile",
@@ -66,115 +69,26 @@ def onehot_scatter_add(counts: jax.Array, syn_idx: jax.Array,
     """counts [n, d, w] += one-hot scatter of T updates. All dims must be
     multiples of their tiles (ops.py pads).
 
-    syn_idx [T] i32, idx [T, d] i32, values [T] f32, signs [T, d] f32.
-    Returns the *delta* accumulated into a fresh buffer plus `counts`.
+    syn_idx [1, T] i32 (-1 matches no row), idx [d, T] i32, values [1, T]
+    f32 (mask folded in), signs [d, T] f32. Updates ``counts`` in place.
     """
     n, d, w = counts.shape
-    t_total = syn_idx.shape[0]
-    grid = (d, n // s_tile, w // w_tile, t_total // t_tile)
-
-    delta = pl.pallas_call(
-        functools.partial(_kernel, s_tile=s_tile, w_tile=w_tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((t_tile,), lambda j, s, w_, t: (t,)),
-            pl.BlockSpec((t_tile, 1), lambda j, s, w_, t: (t, j)),
-            pl.BlockSpec((t_tile,), lambda j, s, w_, t: (t,)),
-            pl.BlockSpec((t_tile, 1), lambda j, s, w_, t: (t, j)),
-        ],
-        out_specs=pl.BlockSpec((s_tile, 1, w_tile),
-                               lambda j, s, w_, t: (s, j, w_)),
-        out_shape=jax.ShapeDtypeStruct((n, d, w), jnp.float32),
-        interpret=interpret,
-    )(syn_idx, idx, values, signs)
-    return counts + delta
-
-
-# ---------------------------------------------------------------------------
-# fused probe + scatter: ONE HBM pass. The routing-table mirror rides into
-# VMEM as whole-array blocks; the first (j=0, s=0, w=0) sweep over T probes
-# each batch tile ONCE and caches the routed rows in a VMEM scratch shared
-# across the sequential grid; every later output tile re-reads the scratch.
-# The counts block is folded into the t == 0 accumulation (no separate
-# delta buffer, no `counts + delta` second pass) and the counts operand is
-# aliased to the output, so the state is updated in place.
-# ---------------------------------------------------------------------------
-def _fused_kernel(cnt_ref, klo_ref, khi_ref, trw_ref, slo_ref, shi_ref,
-                  idx_ref, val_ref, sgn_ref, out_ref, syn_ref, *,
-                  s_tile, w_tile, t_tile, n_probe):
-    j = pl.program_id(0)
-    s = pl.program_id(1)
-    w_ = pl.program_id(2)
-    t = pl.program_id(3)
-
-    @pl.when((j == 0) & (s == 0) & (w_ == 0))
-    def _probe():
-        syn_ref[pl.ds(t * t_tile, t_tile)] = probe.probe_rows(
-            klo_ref[...], khi_ref[...], trw_ref[...],
-            slo_ref[...], shi_ref[...], n_probe=n_probe)
-
-    syn = syn_ref[pl.ds(t * t_tile, t_tile)]        # -1 => matches no row
-    idx = idx_ref[..., 0]
-    val = val_ref[...] * sgn_ref[..., 0]
-
-    s_ids = s * s_tile + jax.lax.broadcasted_iota(jnp.int32, (1, s_tile), 1)
-    w_ids = w_ * w_tile + jax.lax.broadcasted_iota(jnp.int32, (1, w_tile), 1)
-    a = jnp.where(syn[:, None] == s_ids, val[:, None], 0.0)      # [T_t, S_t]
-    b = (idx[:, None] == w_ids).astype(jnp.float32)              # [T_t, W_t]
-    tile = jax.lax.dot_general(
-        a, b, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                      # [S_t, W_t]
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = cnt_ref[...] + tile[:, None, :]
-
-    @pl.when(t > 0)
-    def _acc():
-        out_ref[...] += tile[:, None, :]
-
-
-@functools.partial(jax.jit, static_argnames=("n_probe", "s_tile", "w_tile",
-                                             "t_tile", "interpret"))
-def onehot_probe_scatter(counts: jax.Array, keys_lo: jax.Array,
-                         keys_hi: jax.Array, table_rows: jax.Array,
-                         sid_lo: jax.Array, sid_hi: jax.Array,
-                         idx: jax.Array, values: jax.Array,
-                         signs: jax.Array, *, n_probe: int,
-                         s_tile: int = 128, w_tile: int = 256,
-                         t_tile: int = 512,
-                         interpret: bool = True) -> jax.Array:
-    """Fused routing probe + one-hot scatter-add, one HBM pass.
-
-    counts [n, d, w] f32; keys_lo/keys_hi/table_rows: the routing-table
-    device mirror (pow2 size); sid_lo/sid_hi [T] uint32 stream-id halves;
-    idx [T, d] i32, values [T] f32 (mask pre-folded), signs [T, d] f32.
-    All dims must be tile multiples (ops.py pads; padded tuples carry
-    value 0 and/or an unroutable sid, so they are no-ops).
-    """
-    n, d, w = counts.shape
-    t_total = sid_lo.shape[0]
-    size = keys_lo.shape[0]
-    grid = (d, n // s_tile, w // w_tile, t_total // t_tile)
-    tbl = lambda: pl.BlockSpec((size,), lambda j, s, w_, t: (0,))
+    t_total = syn_idx.shape[1]
+    grid = (n // s_tile, w // w_tile, t_total // t_tile)
     return pl.pallas_call(
-        functools.partial(_fused_kernel, s_tile=s_tile, w_tile=w_tile,
-                          t_tile=t_tile, n_probe=n_probe),
+        functools.partial(_kernel, s_tile=s_tile, w_tile=w_tile,
+                          t_tile=t_tile, depth=d),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((s_tile, 1, w_tile), lambda j, s, w_, t: (s, j, w_)),
-            tbl(), tbl(), tbl(),
-            pl.BlockSpec((t_tile,), lambda j, s, w_, t: (t,)),
-            pl.BlockSpec((t_tile,), lambda j, s, w_, t: (t,)),
-            pl.BlockSpec((t_tile, 1), lambda j, s, w_, t: (t, j)),
-            pl.BlockSpec((t_tile,), lambda j, s, w_, t: (t,)),
-            pl.BlockSpec((t_tile, 1), lambda j, s, w_, t: (t, j)),
+            pl.BlockSpec((1, t_tile), lambda s, w_, t: (0, t)),
+            pl.BlockSpec((d, t_tile), lambda s, w_, t: (0, t)),
+            pl.BlockSpec((1, t_tile), lambda s, w_, t: (0, t)),
+            pl.BlockSpec((d, t_tile), lambda s, w_, t: (0, t)),
+            pl.BlockSpec((s_tile, d, w_tile), lambda s, w_, t: (s, 0, w_)),
         ],
-        out_specs=pl.BlockSpec((s_tile, 1, w_tile),
-                               lambda j, s, w_, t: (s, j, w_)),
+        out_specs=pl.BlockSpec((s_tile, d, w_tile),
+                               lambda s, w_, t: (s, 0, w_)),
         out_shape=jax.ShapeDtypeStruct((n, d, w), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((t_total,), jnp.int32)],
-        input_output_aliases={0: 0},
+        input_output_aliases={4: 0},
         interpret=interpret,
-    )(counts, keys_lo, keys_hi, table_rows, sid_lo, sid_hi,
-      idx, values, signs)
+    )(syn_idx, idx, values, signs, counts)

@@ -4,22 +4,20 @@ registry.
 These are the entry points the engine uses. Each wrapper:
   * does the hashing / layout prep in plain jnp (cheap, fusable),
   * pads every dimension to its kernel tile,
-  * picks interpret mode automatically (True off-TPU, so the kernels
-    VALIDATE on CPU and compile natively on TPU; override with
-    ``SDE_PALLAS_INTERPRET=0/1``),
+  * runs the kernel compiled by Mosaic on a TPU backend and in interpret
+    mode elsewhere, so the kernels VALIDATE on CPU (see ``_interpret``),
   * exposes the same signature as the core/ scatter path so the engine
     can flip between `backend="xla"` and `backend="pallas"`.
 
 Kernel dispatch is a REGISTRY, not a type ladder: a kind declares
 ``update_kernel = "<name>"`` and :func:`resolve_update_kernel` returns the
-matching builder's update function — uniform signature, probe fused into
-the kernel when ``SDE_FUSED_PROBE`` is on (the default). Kinds without a
-declaration fall back to ``batched.stacked_update`` in the engine.
+matching builder's update function, with the signature of
+``batched.stacked_update`` minus the kind. Kinds without a declaration
+fall back to ``batched.stacked_update`` in the engine.
 """
 from __future__ import annotations
 
 import collections
-import functools
 import logging
 import os
 from typing import Any, Callable, Dict
@@ -30,21 +28,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import batched, federated, hashing
-from . import (bitset_or, fm_bitmap, hll_max, onehot_matmul, probe,
-               rhp_project, sliding_dft, pairwise_corr as pc)
+from . import (max_scatter, onehot_matmul, rhp_project, sliding_dft,
+               pairwise_corr as pc)
 
 _logger = logging.getLogger("repro.kernels")
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    # jax <= 0.4 compat: experimental location, check_vma was check_rep
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _experimental_sm(f, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_rep=check_vma)
-
 
 _TRUTHY = ("1", "true", "yes", "on")
 _FALSY = ("0", "false", "no", "off")
@@ -52,34 +39,32 @@ _interpret_logged = False
 
 
 def _interpret() -> bool:
-    """Pallas interpret mode: auto (True off-TPU) unless overridden by
-    ``SDE_PALLAS_INTERPRET`` (1/true/yes/on or 0/false/no/off). The chosen
-    mode is logged once per process. Read at trace time — flipping the env
-    var mid-session only affects programs not yet traced."""
+    """Pallas interpret mode: never on a TPU backend, where every kernel
+    is compiled by Mosaic (an explicit ``SDE_PALLAS_INTERPRET=1`` there
+    raises); on by default elsewhere, so the kernels validate on CPU.
+    ``SDE_PALLAS_INTERPRET=0`` off-TPU forces Mosaic lowering — how a CPU
+    process compiles the kernels for a described TPU. The chosen mode is
+    logged once per process. Read at trace time — flipping the env var
+    mid-session only affects programs not yet traced."""
     global _interpret_logged
     raw = os.environ.get("SDE_PALLAS_INTERPRET", "").strip().lower()
-    if raw in _TRUTHY:
-        mode, why = True, f"SDE_PALLAS_INTERPRET={raw}"
-    elif raw in _FALSY:
-        mode, why = False, f"SDE_PALLAS_INTERPRET={raw}"
-    elif raw:
+    backend = jax.default_backend()
+    if raw and raw not in _TRUTHY + _FALSY:
         raise ValueError(
             f"SDE_PALLAS_INTERPRET={raw!r} not understood — use one of "
             f"{_TRUTHY + _FALSY} or unset for auto")
+    if backend == "tpu" and raw in _TRUTHY:
+        raise ValueError(
+            f"SDE_PALLAS_INTERPRET={raw} on a TPU backend: the kernels "
+            "run compiled there, never interpreted — unset it")
+    if raw:
+        mode, why = raw in _TRUTHY, f"SDE_PALLAS_INTERPRET={raw}"
     else:
-        mode = jax.default_backend() != "tpu"
-        why = f"auto (jax backend: {jax.default_backend()})"
+        mode, why = backend != "tpu", f"auto (jax backend: {backend})"
     if not _interpret_logged:
         _logger.info("pallas interpret mode: %s [%s]", mode, why)
         _interpret_logged = True
     return mode
-
-
-def probe_fusion_enabled() -> bool:
-    """Whether registry kernels fuse the routing probe into the Pallas
-    grid (one HBM pass) — on unless ``SDE_FUSED_PROBE`` is falsy."""
-    return os.environ.get("SDE_FUSED_PROBE", "1").strip().lower() \
-        not in _FALSY
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int = 0, value=0):
@@ -92,20 +77,22 @@ def _pad_to(x: jax.Array, mult: int, axis: int = 0, value=0):
     return jnp.pad(x, pad, constant_values=value)
 
 
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
 # ---------------------------------------------------------------------------
 # blue path: hashed stream routing. The engine keeps each kind stack's
 # stream->row map in an open-addressing hash table (service/routing.py
 # owns the host-side inserts); this is the device half — a vectorized
 # fixed-bound linear probe traced INSIDE the fused update programs, so
 # routing arbitrary 63-bit stream ids still costs zero extra dispatches.
-# The probe math lives in kernels/probe.py so the SAME code runs both as
-# plain jnp here and inside the Pallas grids of the fused kernels.
 # ---------------------------------------------------------------------------
 
-# hi half of an empty slot; valid ids < 2**63 have hi <= 2**31-1. Batch
-# padding uses it as the sid hi so padded lanes probe to row -1. (numpy
-# scalar: a bare python int overflows jit's weak-int32 argument parsing)
-_EMPTY_HI = np.uint32(0xFFFFFFFF)
+# numpy scalars: a bare python int overflows jit's weak-int32 parsing
+_ROUTE_GOLDEN = np.uint32(0x9E3779B9)
+_EMPTY_HI = np.uint32(0xFFFFFFFF)   # hi half of an empty slot; valid ids
+                                    # < 2**63 have hi <= 2**31-1
 
 
 def route_probe(keys_lo: jax.Array, keys_hi: jax.Array, rows: jax.Array,
@@ -119,18 +106,26 @@ def route_probe(keys_lo: jax.Array, keys_hi: jax.Array, rows: jax.Array,
     plain jnp, fusable into the caller's single blue-path dispatch. The
     slot hash must stay in lockstep with ``service.routing.slot_hash``.
     """
-    return probe.probe_rows(keys_lo, keys_hi, rows,
-                            sid_lo.astype(jnp.uint32),
-                            sid_hi.astype(jnp.uint32), n_probe=n_probe)
+    size_mask = jnp.int32(keys_lo.shape[0] - 1)
+    sid_lo = sid_lo.astype(jnp.uint32)
+    sid_hi = sid_hi.astype(jnp.uint32)
+    h = hashing.mix32(sid_lo ^ hashing.mix32(sid_hi ^ _ROUTE_GOLDEN))
+    slot0 = (h & jnp.uint32(keys_lo.shape[0] - 1)).astype(jnp.int32)
 
+    def body(_, carry):
+        row, slot, done = carry
+        k_hi = keys_hi[slot]
+        hit = (keys_lo[slot] == sid_lo) & (k_hi == sid_hi)
+        empty = k_hi == _EMPTY_HI
+        row = jnp.where(hit & ~done, rows[slot], row)
+        done = done | hit | empty
+        slot = jnp.where(done, slot, (slot + 1) & size_mask)
+        return row, slot, done
 
-def _pad_sids(sid_lo: jax.Array, sid_hi: jax.Array, t_tile: int):
-    """Pad a stream-id batch to the T tile: padded lanes get the
-    empty-slot hi pattern, which no occupied table slot carries, so the
-    in-kernel probe resolves them to -1 (match nothing)."""
-    lo = _pad_to(sid_lo.astype(jnp.uint32), t_tile)
-    hi = _pad_to(sid_hi.astype(jnp.uint32), t_tile, value=_EMPTY_HI)
-    return lo, hi
+    row0 = jnp.full(sid_lo.shape, -1, jnp.int32)
+    done0 = jnp.zeros(sid_lo.shape, bool)
+    row, _, _ = jax.lax.fori_loop(0, n_probe, body, (row0, slot0, done0))
+    return row
 
 
 def _source_fold(out: jax.Array, idx: jax.Array, contrib: jax.Array,
@@ -433,7 +428,7 @@ def _estimate_collective_fn(kind, mesh, axis_name):
                 return batched.stacked_estimate(
                     kind, one, jnp.zeros((1,), jnp.int32), *qargs)
 
-            fn = _shard_map(shard_fn, mesh=mesh,
+            fn = jax.shard_map(shard_fn, mesh=mesh,
                             in_specs=(P(axis_name),) + (P(),) * len(
                                 query_args),
                             out_specs=P(), check_vma=False)
@@ -461,36 +456,27 @@ def estimate_collective(kind, states_stacked, *query_args, mesh, axis_name):
 
 
 # ---------------------------------------------------------------------------
-# blue path: per-kind update wrappers. Every wrapper takes EITHER routed
-# rows (``syn_idx``, -1 = drop) or a ``route`` tuple
-# ``(keys_lo, keys_hi, table_rows, sid_lo, sid_hi, n_probe)`` — the second
-# form fuses the routing probe into the Pallas grid so state + table are
-# read in ONE HBM pass per batch.
+# blue path: per-kind update wrappers. Every wrapper takes routed rows
+# (``syn_idx``, -1 = drop) — the engine runs ``route_probe`` ahead of the
+# kernel inside the same jitted dispatch. ``source_rows`` indexes
+# data-source rows fed by every unmasked tuple: their delta is one fresh
+# single sketch of the batch merged into just those rows (O(source rows),
+# not O(capacity)), fused into the same dispatch.
 # ---------------------------------------------------------------------------
 
 
 def countmin_update(counts: jax.Array, syn_idx: jax.Array, items: jax.Array,
                     values: jax.Array, mask: jax.Array, *, seeds: jax.Array,
                     log2_width: int, weighted: bool = True,
-                    source_rows: jax.Array | None = None,
-                    source_tuple_mask: jax.Array | None = None) -> jax.Array:
-    """Pallas-backed stacked CountMin update. counts [n, d, w].
-
-    ``source_rows`` indexes data-source rows fed by every tuple under
-    ``source_tuple_mask`` [T] (defaults to all tuples): their delta is
-    accumulated ONCE as a fresh single sketch and broadcast-added (CM is
-    linear), fused into the same dispatch as the routed kernel scatter.
-    """
-    n, d, w = counts.shape
+                    source_rows: jax.Array | None = None) -> jax.Array:
+    """Pallas-backed stacked CountMin update. counts [n, d, w]."""
     idx = hashing.bucket_hash(items, seeds, log2_width)
     v = values if weighted else jnp.ones_like(values)
     vm = v * mask.astype(jnp.float32)
-    signs = jnp.ones((items.shape[0], d), jnp.float32)
-    out = _scatter_call(counts, syn_idx, idx, vm, signs)
+    out = _scatter_call(counts, syn_idx, idx, vm, jnp.ones(idx.shape,
+                                                           jnp.float32))
     if source_rows is not None:
-        tm = mask if source_tuple_mask is None else source_tuple_mask
-        vs = (v * tm.astype(jnp.float32))[:, None]
-        out = _source_fold(out, idx, jnp.broadcast_to(vs, idx.shape),
+        out = _source_fold(out, idx, jnp.broadcast_to(vm[:, None], idx.shape),
                            source_rows)
     return out
 
@@ -498,64 +484,45 @@ def countmin_update(counts: jax.Array, syn_idx: jax.Array, items: jax.Array,
 def ams_update(counts: jax.Array, syn_idx: jax.Array, items: jax.Array,
                values: jax.Array, mask: jax.Array, *, seeds: jax.Array,
                log2_width: int,
-               source_rows: jax.Array | None = None,
-               source_tuple_mask: jax.Array | None = None) -> jax.Array:
+               source_rows: jax.Array | None = None) -> jax.Array:
     """Pallas-backed stacked AMS/count-sketch update. counts [n, d, w]."""
     idx = hashing.bucket_hash(items, seeds, log2_width)
     sgn = hashing.sign_hash(items, seeds)
     v = values * mask.astype(jnp.float32)
     out = _scatter_call(counts, syn_idx, idx, v, sgn)
     if source_rows is not None:
-        tm = mask if source_tuple_mask is None else source_tuple_mask
-        vs = (values * tm.astype(jnp.float32))[:, None] * sgn
-        out = _source_fold(out, idx, vs, source_rows)
+        out = _source_fold(out, idx, v[:, None] * sgn, source_rows)
     return out
 
 
-def _scatter_call(counts, syn_idx, idx, values, signs, *, route=None):
+def _scatter_call(counts, syn_idx, idx, values, signs):
+    """counts [n, d, w] += one-hot scatter; idx/signs [T, d]."""
     n, d, w = counts.shape
     t_tile = 512
-    s_tile = min(128, n) if n % min(128, n) == 0 else n
+    s_tile = min(128, _round_up(n, 8))
     w_tile = min(256, w)
-    # pad T; padded rows get syn_idx = -1 / an unroutable sid -> match
-    # nothing (values are also padded to 0)
-    idx = _pad_to(idx.astype(jnp.int32), t_tile, value=-1)
-    values = _pad_to(values.astype(jnp.float32), t_tile)
-    signs = _pad_to(signs.astype(jnp.float32), t_tile)
-    # pad n/w to tiles
-    n_pad = (-n) % s_tile
-    w_pad = (-w) % w_tile
-    padded = jnp.pad(counts, ((0, n_pad), (0, 0), (0, w_pad)))
-    if route is None:
-        syn_idx = _pad_to(syn_idx.astype(jnp.int32), t_tile, value=-1)
-        out = onehot_matmul.onehot_scatter_add(
-            padded, syn_idx, idx, values, signs, s_tile=s_tile,
-            w_tile=w_tile, t_tile=t_tile, interpret=_interpret())
-    else:
-        klo, khi, trows, slo, shi, n_probe = route
-        slo, shi = _pad_sids(slo, shi, t_tile)
-        out = onehot_matmul.onehot_probe_scatter(
-            padded, klo, khi, trows, slo, shi, idx, values, signs,
-            n_probe=n_probe, s_tile=s_tile, w_tile=w_tile, t_tile=t_tile,
-            interpret=_interpret())
+    # padded tuples get syn -1 / value 0 -> match nothing
+    syn = _pad_to(syn_idx.astype(jnp.int32), t_tile, value=-1)[None, :]
+    idx = _pad_to(idx.astype(jnp.int32), t_tile, value=-1).T
+    values = _pad_to(values.astype(jnp.float32), t_tile)[None, :]
+    signs = _pad_to(signs.astype(jnp.float32), t_tile).T
+    padded = jnp.pad(counts, ((0, (-n) % s_tile), (0, 0), (0, (-w) % w_tile)))
+    out = onehot_matmul.onehot_scatter_add(
+        padded, syn, idx, values, signs, s_tile=s_tile, w_tile=w_tile,
+        t_tile=t_tile, interpret=_interpret())
     return out[:n, :, :w]
 
 
 def hll_update(regs: jax.Array, syn_idx: jax.Array, items: jax.Array,
                mask: jax.Array, *, seed: int, p: int,
-               source_rows: jax.Array | None = None,
-               source_tuple_mask: jax.Array | None = None) -> jax.Array:
+               source_rows: jax.Array | None = None) -> jax.Array:
     """Pallas-backed stacked HLL update. regs [n, m]. Data-source rows
-    (``source_rows``) take an elementwise max with a fresh single-HLL of
-    the batch — merge = max, fused into the same dispatch."""
+    take an elementwise max with a fresh single-HLL of the batch."""
     bucket, raw_rank = _hll_prep(items, seed, p)
     rank = jnp.where(mask, raw_rank, 0).astype(jnp.int32)
-    out = _hll_call(regs, syn_idx, bucket, rank)
+    out = _max_call(regs, syn_idx, bucket[:, None], rank)
     if source_rows is not None:
-        tm = mask if source_tuple_mask is None else source_tuple_mask
-        src_rank = jnp.where(tm, raw_rank, 0).astype(jnp.int32)
-        fresh = jnp.zeros((regs.shape[1],), jnp.int32).at[bucket].max(
-            src_rank)
+        fresh = jnp.zeros((regs.shape[1],), jnp.int32).at[bucket].max(rank)
         out = out.at[source_rows].max(fresh[None, :])
     return out
 
@@ -568,126 +535,60 @@ def _hll_prep(items, seed: int, p: int):
     return bucket, raw_rank
 
 
-def _hll_call(regs, syn_idx, bucket, rank, *, route=None):
-    n, m = regs.shape
-    t_tile = 128
-    s_tile = min(8, n)
-    m_tile = min(128, m)
-    bucket = _pad_to(bucket.astype(jnp.int32), t_tile)
-    rank = _pad_to(rank.astype(jnp.int32), t_tile)   # pad rank 0 => no-op
-    n_pad = (-n) % s_tile
-    m_pad = (-m) % m_tile
-    padded = jnp.pad(regs, ((0, n_pad), (0, m_pad)))
-    if route is None:
-        syn_idx = _pad_to(syn_idx.astype(jnp.int32), t_tile, value=-1)
-        out = hll_max.hll_max_update(
-            padded, syn_idx, bucket, rank, s_tile=s_tile, m_tile=m_tile,
-            t_tile=t_tile, interpret=_interpret())
-    else:
-        klo, khi, trows, slo, shi, n_probe = route
-        slo, shi = _pad_sids(slo, shi, t_tile)
-        out = hll_max.hll_probe_max_update(
-            padded, klo, khi, trows, slo, shi, bucket, rank,
-            n_probe=n_probe, s_tile=s_tile, m_tile=m_tile, t_tile=t_tile,
-            interpret=_interpret())
-    return out[:n, :m]
-
-
 def bloom_update(bits: jax.Array, syn_idx: jax.Array, items: jax.Array,
                  mask: jax.Array, *, seeds: jax.Array, log2_bits: int,
-                 source_rows: jax.Array | None = None,
-                 source_tuple_mask: jax.Array | None = None) -> jax.Array:
+                 source_rows: jax.Array | None = None) -> jax.Array:
     """Pallas-backed stacked Bloom update. bits [n, m] int32 0/1; each
     tuple ORs its k hash positions into its routed row. Data-source rows
     take the OR (== max) of a fresh single-filter of the batch."""
     idx = hashing.bucket_hash(items, seeds, log2_bits)          # [T, k]
     upd = mask.astype(jnp.int32)
-    out = _bitset_call(bits, syn_idx, idx, upd)
+    out = _max_call(bits, syn_idx, idx, upd)
     if source_rows is not None:
-        tm = mask if source_tuple_mask is None else source_tuple_mask
-        out = out.at[source_rows].max(
-            _bloom_fresh(bits.shape[1], idx, tm)[None])
-    return out
-
-
-def _bloom_fresh(m: int, idx, tuple_mask):
-    u = jnp.broadcast_to(tuple_mask.astype(jnp.int32)[:, None], idx.shape)
-    return jnp.zeros((m,), jnp.int32).at[idx].max(u)
-
-
-def _bitset_call(bits, syn_idx, idx, upd, *, route=None):
-    n, m = bits.shape
-    t_tile = 128
-    s_tile = min(8, n)
-    m_tile = min(128, m)
-    idx = _pad_to(idx.astype(jnp.int32), t_tile, value=-1)
-    upd = _pad_to(upd.astype(jnp.int32), t_tile)     # pad upd 0 => no-op
-    n_pad = (-n) % s_tile
-    m_pad = (-m) % m_tile
-    padded = jnp.pad(bits, ((0, n_pad), (0, m_pad)))
-    if route is None:
-        syn_idx = _pad_to(syn_idx.astype(jnp.int32), t_tile, value=-1)
-        out = bitset_or.bitset_max_update(
-            padded, syn_idx, idx, upd, s_tile=s_tile, m_tile=m_tile,
-            t_tile=t_tile, interpret=_interpret())
-    else:
-        klo, khi, trows, slo, shi, n_probe = route
-        slo, shi = _pad_sids(slo, shi, t_tile)
-        out = bitset_or.bitset_probe_max_update(
-            padded, klo, khi, trows, slo, shi, idx, upd, n_probe=n_probe,
-            s_tile=s_tile, m_tile=m_tile, t_tile=t_tile,
-            interpret=_interpret())
-    return out[:n, :m]
-
-
-def fm_update(state: jax.Array, syn_idx: jax.Array, which: jax.Array,
-              pos: jax.Array, mask: jax.Array, *,
-              source_rows: jax.Array | None = None,
-              source_tuple_mask: jax.Array | None = None) -> jax.Array:
-    """Pallas-backed stacked FM/PCSA update. state [n, maps, bits] int32
-    0/1; each tuple sets bit (which, pos) of its routed row. The caller
-    provides (which, pos) from the kind's hash split (``FMSketch
-    ._which_pos``)."""
-    upd = mask.astype(jnp.int32)
-    out = _fm_call(state, syn_idx, which, pos, upd)
-    if source_rows is not None:
-        tm = mask if source_tuple_mask is None else source_tuple_mask
-        fresh = jnp.zeros(state.shape[1:], jnp.int32).at[which, pos].max(
-            tm.astype(jnp.int32))
+        u = jnp.broadcast_to(upd[:, None], idx.shape)
+        fresh = jnp.zeros((bits.shape[1],), jnp.int32).at[idx].max(u)
         out = out.at[source_rows].max(fresh[None])
     return out
 
 
-def _fm_call(state, syn_idx, which, pos, upd, *, route=None):
-    n = state.shape[0]
-    q = state.shape[1] * state.shape[2]
-    t_tile = 128
-    s_tile = min(8, n)
-    m_tile = min(128, q)
-    which = _pad_to(which.astype(jnp.int32), t_tile)
-    pos = _pad_to(pos.astype(jnp.int32), t_tile)
-    upd = _pad_to(upd.astype(jnp.int32), t_tile)     # pad upd 0 => no-op
-    n_pad = (-n) % s_tile
-    padded = jnp.pad(state, ((0, n_pad), (0, 0), (0, 0)))
-    if route is None:
-        syn_idx = _pad_to(syn_idx.astype(jnp.int32), t_tile, value=-1)
-        out = fm_bitmap.fm_bit_update(
-            padded, syn_idx, which, pos, upd, s_tile=s_tile, m_tile=m_tile,
-            t_tile=t_tile, interpret=_interpret())
-    else:
-        klo, khi, trows, slo, shi, n_probe = route
-        slo, shi = _pad_sids(slo, shi, t_tile)
-        out = fm_bitmap.fm_probe_bit_update(
-            padded, klo, khi, trows, slo, shi, which, pos, upd,
-            n_probe=n_probe, s_tile=s_tile, m_tile=m_tile, t_tile=t_tile,
-            interpret=_interpret())
-    return out[:n]
+def fm_update(state: jax.Array, syn_idx: jax.Array, which: jax.Array,
+              pos: jax.Array, mask: jax.Array, *,
+              source_rows: jax.Array | None = None) -> jax.Array:
+    """Pallas-backed stacked FM/PCSA update. state [n, maps, bits] int32
+    0/1; each tuple sets bit (which, pos) of its routed row — one
+    position of the row-major flattened [maps * bits] plane. The caller
+    provides (which, pos) from the kind's hash split (``FMSketch
+    ._which_pos``)."""
+    n, maps, nbits = state.shape
+    upd = mask.astype(jnp.int32)
+    flat_pos = (which * nbits + pos).astype(jnp.int32)
+    out = _max_call(state.reshape(n, maps * nbits), syn_idx,
+                    flat_pos[:, None], upd).reshape(state.shape)
+    if source_rows is not None:
+        fresh = jnp.zeros((maps, nbits), jnp.int32).at[which, pos].max(upd)
+        out = out.at[source_rows].max(fresh[None])
+    return out
+
+
+def _max_call(state, syn_idx, pos, upd):
+    """state [n, m] i32 max-scatter; pos [T, k] positions, upd [T]."""
+    n, m = state.shape
+    t_tile = 256
+    s_tile = 8
+    m_tile = m if m <= 512 else 512
+    syn = _pad_to(syn_idx.astype(jnp.int32), t_tile, value=-1)[:, None]
+    pos = _pad_to(pos.astype(jnp.int32), t_tile, value=-1)
+    upd = _pad_to(upd.astype(jnp.int32), t_tile)[:, None]  # pad 0 => no-op
+    padded = jnp.pad(state, ((0, (-n) % s_tile), (0, (-m) % m_tile)))
+    out = max_scatter.max_scatter_update(
+        padded, syn, pos, upd, s_tile=s_tile, m_tile=m_tile, t_tile=t_tile,
+        interpret=_interpret())
+    return out[:n, :m]
 
 
 def rhp_update(state: jax.Array, syn_idx: jax.Array, items: jax.Array,
                values: jax.Array, mask: jax.Array, *, seeds: jax.Array,
-               source_rows: jax.Array | None = None,
-               source_tuple_mask: jax.Array | None = None) -> jax.Array:
+               source_rows: jax.Array | None = None) -> jax.Array:
     """Pallas-backed stacked RHP/SimHash update. state [n, b] f32; each
     tuple adds ``v * sign_row`` into its routed row (dense — a matmul).
     Data-source rows add the batch's summed projection (linear merge)."""
@@ -695,36 +596,24 @@ def rhp_update(state: jax.Array, syn_idx: jax.Array, items: jax.Array,
     v = values * mask.astype(jnp.float32)
     out = _rhp_call(state, syn_idx, v, sgn)
     if source_rows is not None:
-        tm = mask if source_tuple_mask is None else source_tuple_mask
-        vs = (values * tm.astype(jnp.float32))[:, None]
-        out = out.at[source_rows].add(jnp.sum(sgn * vs, axis=0)[None])
+        out = out.at[source_rows].add(jnp.sum(sgn * v[:, None], axis=0)[None])
     return out
 
 
-def _rhp_call(state, syn_idx, values, signs, *, route=None):
+def _rhp_call(state, syn_idx, values, signs):
     n, b = state.shape
     t_tile = 512
-    s_tile = min(128, n) if n % min(128, n) == 0 else n
+    s_tile = min(128, _round_up(n, 8))
     b_tile = min(128, b)
-    values = _pad_to(values.astype(jnp.float32), t_tile)
+    syn = _pad_to(syn_idx.astype(jnp.int32), t_tile, value=-1)[None, :]
+    values = _pad_to(values.astype(jnp.float32), t_tile)[None, :]
     signs = _pad_to(signs.astype(jnp.float32), t_tile)
-    n_pad = (-n) % s_tile
     b_pad = (-b) % b_tile
-    padded = jnp.pad(state, ((0, n_pad), (0, b_pad)))
-    if b_pad:
-        signs = jnp.pad(signs, ((0, 0), (0, b_pad)))
-    if route is None:
-        syn_idx = _pad_to(syn_idx.astype(jnp.int32), t_tile, value=-1)
-        out = rhp_project.rhp_project_update(
-            padded, syn_idx, values, signs, s_tile=s_tile, b_tile=b_tile,
-            t_tile=t_tile, interpret=_interpret())
-    else:
-        klo, khi, trows, slo, shi, n_probe = route
-        slo, shi = _pad_sids(slo, shi, t_tile)
-        out = rhp_project.rhp_probe_update(
-            padded, klo, khi, trows, slo, shi, values, signs,
-            n_probe=n_probe, s_tile=s_tile, b_tile=b_tile, t_tile=t_tile,
-            interpret=_interpret())
+    signs = jnp.pad(signs, ((0, 0), (0, b_pad)))
+    padded = jnp.pad(state, ((0, (-n) % s_tile), (0, b_pad)))
+    out = rhp_project.rhp_project_update(
+        padded, syn, values, signs, s_tile=s_tile, b_tile=b_tile,
+        t_tile=t_tile, interpret=_interpret())
     return out[:n, :b]
 
 
@@ -732,18 +621,13 @@ def _rhp_call(state, syn_idx, values, signs, *, route=None):
 # the update-kernel registry. A kind opts into the Pallas blue path by
 # declaring ``update_kernel = "<name>"``; the engine resolves the name here
 # at dispatch time — no isinstance ladder anywhere. Every registered
-# builder returns an update fn with the SAME signature:
+# builder returns an update fn with the signature of
+# ``batched.stacked_update`` minus the kind:
 #
-#     fn(state, keys_lo, keys_hi, table_rows, sid_lo, sid_hi,
-#        items, values, mask, source_rows, *, n_probe) -> state'
+#     fn(state, syn_idx, items, values, mask, source_rows) -> state'
 #
-# where ``source_rows`` may be None and ``n_probe`` is static. When built
-# with ``fuse_probe=True`` the routing probe runs INSIDE the Pallas grid
-# (one HBM pass over state + table per batch); with False it runs as the
-# jnp ``route_probe`` ahead of the plain scatter kernel (two logical
-# passes, same results — the equivalence tests flip ``SDE_FUSED_PROBE``).
-# The per-batch source fold stays outside the kernel either way: it is
-# O(source rows), not O(capacity), and fuses into the same dispatch.
+# where ``syn_idx`` holds routed rows (-1 = unrouted) and ``source_rows``
+# may be None.
 # ---------------------------------------------------------------------------
 
 UPDATE_KERNELS: Dict[str, Callable] = {}
@@ -751,8 +635,8 @@ UPDATE_KERNELS: Dict[str, Callable] = {}
 
 def register_update_kernel(name: str, builder: Callable, *,
                            overwrite: bool = False) -> None:
-    """Register ``builder(kind, fuse_probe) -> update_fn`` under ``name``.
-    Kinds reference kernels by name (``update_kernel = name``), so plugged
+    """Register ``builder(kind) -> update_fn`` under ``name``. Kinds
+    reference kernels by name (``update_kernel = name``), so plugged
     kinds can reuse a stock kernel or bring their own without the engine
     learning any new types."""
     if name in UPDATE_KERNELS and not overwrite:
@@ -761,11 +645,10 @@ def register_update_kernel(name: str, builder: Callable, *,
     UPDATE_KERNELS[name] = builder
 
 
-def resolve_update_kernel(kind, fuse_probe: bool | None = None):
+def resolve_update_kernel(kind):
     """The registry lookup the engine dispatches through: returns the
     kind's built update fn, or None when the kind declares no
-    ``update_kernel`` (engine falls back to ``batched.stacked_update``).
-    ``fuse_probe`` defaults to :func:`probe_fusion_enabled`."""
+    ``update_kernel`` (engine falls back to ``batched.stacked_update``)."""
     name = getattr(kind, "update_kernel", None)
     if name is None:
         return None
@@ -776,115 +659,30 @@ def resolve_update_kernel(kind, fuse_probe: bool | None = None):
             f"such kernel is registered — register_update_kernel({name!r}, "
             "builder) first, or drop the declaration to use the XLA "
             "fallback")
-    if fuse_probe is None:
-        fuse_probe = probe_fusion_enabled()
-    return builder(kind, fuse_probe)
+    return builder(kind)
 
 
-def _route_or_rows(fuse, klo, khi, trows, slo, shi, n_probe):
-    """(route tuple, None) when fusing; (None, routed rows) when not."""
-    if fuse:
-        return (klo, khi, trows, slo, shi, n_probe), None
-    return None, route_probe(klo, khi, trows, slo, shi, n_probe=n_probe)
-
-
-def _countmin_kernel(kind, fuse):
-    def fn(state, klo, khi, trows, slo, shi, items, vals, msk, src_rows, *,
-           n_probe):
-        seeds = kind._seeds()
-        idx = hashing.bucket_hash(items, seeds, kind.log2_width)
-        v = vals if kind.weighted else jnp.ones_like(vals)
-        vm = v * msk.astype(jnp.float32)
-        signs = jnp.ones((items.shape[0], kind.depth), jnp.float32)
-        route, syn = _route_or_rows(fuse, klo, khi, trows, slo, shi, n_probe)
-        out = _scatter_call(state, syn, idx, vm, signs, route=route)
-        if src_rows is not None:
-            out = _source_fold(out, idx,
-                               jnp.broadcast_to(vm[:, None], idx.shape),
-                               src_rows)
-        return out
-    return fn
-
-
-def _ams_kernel(kind, fuse):
-    def fn(state, klo, khi, trows, slo, shi, items, vals, msk, src_rows, *,
-           n_probe):
-        seeds = kind._seeds()
-        idx = hashing.bucket_hash(items, seeds, kind.log2_width)
-        sgn = hashing.sign_hash(items, seeds)
-        v = vals * msk.astype(jnp.float32)
-        route, syn = _route_or_rows(fuse, klo, khi, trows, slo, shi, n_probe)
-        out = _scatter_call(state, syn, idx, v, sgn, route=route)
-        if src_rows is not None:
-            out = _source_fold(out, idx, v[:, None] * sgn, src_rows)
-        return out
-    return fn
-
-
-def _hll_kernel(kind, fuse):
-    def fn(state, klo, khi, trows, slo, shi, items, vals, msk, src_rows, *,
-           n_probe):
-        bucket, raw_rank = _hll_prep(items, kind.seed, kind.p)
-        rank = jnp.where(msk, raw_rank, 0).astype(jnp.int32)
-        route, syn = _route_or_rows(fuse, klo, khi, trows, slo, shi, n_probe)
-        out = _hll_call(state, syn, bucket, rank, route=route)
-        if src_rows is not None:
-            fresh = jnp.zeros((state.shape[1],), jnp.int32).at[bucket].max(
-                rank)
-            out = out.at[src_rows].max(fresh[None, :])
-        return out
-    return fn
-
-
-def _bloom_kernel(kind, fuse):
-    def fn(state, klo, khi, trows, slo, shi, items, vals, msk, src_rows, *,
-           n_probe):
-        idx = hashing.bucket_hash(items, kind._seeds(), kind.log2_bits)
-        upd = msk.astype(jnp.int32)
-        route, syn = _route_or_rows(fuse, klo, khi, trows, slo, shi, n_probe)
-        out = _bitset_call(state, syn, idx, upd, route=route)
-        if src_rows is not None:
-            out = out.at[src_rows].max(
-                _bloom_fresh(state.shape[1], idx, msk)[None])
-        return out
-    return fn
-
-
-def _fm_kernel(kind, fuse):
-    def fn(state, klo, khi, trows, slo, shi, items, vals, msk, src_rows, *,
-           n_probe):
-        which, pos = kind._which_pos(items)
-        upd = msk.astype(jnp.int32)
-        route, syn = _route_or_rows(fuse, klo, khi, trows, slo, shi, n_probe)
-        out = _fm_call(state, syn, which, pos, upd, route=route)
-        if src_rows is not None:
-            fresh = jnp.zeros(state.shape[1:], jnp.int32).at[
-                which, pos].max(upd)
-            out = out.at[src_rows].max(fresh[None])
-        return out
-    return fn
-
-
-def _rhp_kernel(kind, fuse):
-    def fn(state, klo, khi, trows, slo, shi, items, vals, msk, src_rows, *,
-           n_probe):
-        sgn = hashing.sign_hash(items, kind._seeds())
-        v = vals * msk.astype(jnp.float32)
-        route, syn = _route_or_rows(fuse, klo, khi, trows, slo, shi, n_probe)
-        out = _rhp_call(state, syn, v, sgn, route=route)
-        if src_rows is not None:
-            out = out.at[src_rows].add(
-                jnp.sum(sgn * v[:, None], axis=0)[None])
-        return out
-    return fn
-
-
-register_update_kernel("countmin_scatter", _countmin_kernel)
-register_update_kernel("ams_scatter", _ams_kernel)
-register_update_kernel("hll_max", _hll_kernel)
-register_update_kernel("bloom_bitset", _bloom_kernel)
-register_update_kernel("fm_bitmap", _fm_kernel)
-register_update_kernel("rhp_project", _rhp_kernel)
+register_update_kernel("countmin_scatter", lambda kind: (
+    lambda st, syn, it, v, m, src: countmin_update(
+        st, syn, it, v, m, seeds=kind._seeds(), log2_width=kind.log2_width,
+        weighted=kind.weighted, source_rows=src)))
+register_update_kernel("ams_scatter", lambda kind: (
+    lambda st, syn, it, v, m, src: ams_update(
+        st, syn, it, v, m, seeds=kind._seeds(), log2_width=kind.log2_width,
+        source_rows=src)))
+register_update_kernel("hll_max", lambda kind: (
+    lambda st, syn, it, v, m, src: hll_update(
+        st, syn, it, m, seed=kind.seed, p=kind.p, source_rows=src)))
+register_update_kernel("bloom_bitset", lambda kind: (
+    lambda st, syn, it, v, m, src: bloom_update(
+        st, syn, it, m, seeds=kind._seeds(), log2_bits=kind.log2_bits,
+        source_rows=src)))
+register_update_kernel("fm_bitmap", lambda kind: (
+    lambda st, syn, it, v, m, src: fm_update(
+        st, syn, *kind._which_pos(it), m, source_rows=src)))
+register_update_kernel("rhp_project", lambda kind: (
+    lambda st, syn, it, v, m, src: rhp_update(
+        st, syn, it, v, m, seeds=kind._seeds(), source_rows=src)))
 
 
 def dft_step(re: jax.Array, im: jax.Array, delta: jax.Array,

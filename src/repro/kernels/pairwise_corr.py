@@ -21,10 +21,9 @@ def _kernel(xi_ref, xj_ref, sqi_ref, sqj_ref, out_ref):
     xj = xj_ref[...]                       # [J_t, K]
     gram = jax.lax.dot_general(
         xi, xj, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)          # [I_t, J_t]
-    sqi = sqi_ref[...][:, None]
-    sqj = sqj_ref[...][None, :]
-    out_ref[...] = 1.0 - (sqi + sqj - 2.0 * gram)
+    out_ref[...] = 1.0 - (sqi_ref[...] + sqj_ref[...] - 2.0 * gram)
 
 
 @functools.partial(jax.jit, static_argnames=("i_tile", "j_tile", "interpret"))
@@ -40,10 +39,10 @@ def pairwise_corr(x: jax.Array, *, i_tile: int = 256, j_tile: int = 256,
         in_specs=[
             pl.BlockSpec((i_tile, k), lambda i, j: (i, 0)),
             pl.BlockSpec((j_tile, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((i_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((j_tile,), lambda i, j: (j,)),
+            pl.BlockSpec((i_tile, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, j_tile), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((i_tile, j_tile), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
         interpret=interpret,
-    )(x, x, sq, sq)
+    )(x, x, sq[:, None], sq[None, :])

@@ -6,17 +6,14 @@ sign matrix is DENSE (every tuple touches all b planes), the update is a
 pure matmul — no one-hot bucket side:
 
     state[syn, :] += sum_t (syn_t == syn) * v_t * sgn[t, :]
-                   =       A^T @ sgn
-    A[t, syn] = (syn_t == syn) * v_t
+                   =       A @ sgn
+    A[syn, t] = (syn_t == syn) * v_t
 
 i.e. an [S_tile x T_tile] x [T_tile x B_tile] MXU matmul per grid cell,
-the densest of the scatter kernels. Grid: (S_tiles, B_tiles, T_tiles),
-T innermost; the state block folds into the t == 0 accumulation and the
-operand is aliased to the output (in-place, no delta buffer).
-
-:func:`rhp_project_update` takes routed rows; :func:`rhp_probe_update`
-fuses the routing probe into the kernel (one HBM pass; the probe result
-is cached in a VMEM scratch on the first (s=0, b=0) sweep over T).
+the densest of the scatter kernels, at ``Precision.HIGHEST`` (no bf16
+rounding of the f32 values). ``syn``/``values`` ride as [1, T] rows.
+Grid: (S_tiles, B_tiles, T_tiles), T innermost; the state block seeds the
+t == 0 accumulation and the operand is aliased to the output (in place).
 """
 from __future__ import annotations
 
@@ -25,31 +22,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from . import probe
 
 
-def _tile(syn, val, sgn, s, *, s_tile):
-    s_ids = s * s_tile + jax.lax.broadcasted_iota(jnp.int32, (1, s_tile), 1)
-    a = jnp.where(syn[:, None] == s_ids, val[:, None], 0.0)      # [T_t, S_t]
-    return jax.lax.dot_general(
-        a, sgn, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                      # [S_t, B_t]
-
-
-def _kernel(state_ref, syn_ref, val_ref, sgn_ref, out_ref, *, s_tile):
+def _kernel(syn_ref, val_ref, sgn_ref, state_ref, out_ref, *, s_tile,
+            t_tile):
     s = pl.program_id(0)
     t = pl.program_id(2)
-    tile = _tile(syn_ref[...], val_ref[...], sgn_ref[...], s, s_tile=s_tile)
+    s_ids = s * s_tile + jax.lax.broadcasted_iota(
+        jnp.int32, (s_tile, t_tile), 0)
+    a = jnp.where(s_ids == syn_ref[...], val_ref[...], 0.0)     # [S_t, T_t]
 
     @pl.when(t == 0)
     def _init():
-        out_ref[...] = state_ref[...] + tile
+        out_ref[...] = state_ref[...]
 
-    @pl.when(t > 0)
-    def _acc():
-        out_ref[...] += tile
+    out_ref[...] += jax.lax.dot_general(
+        a, sgn_ref[...], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                      # [S_t, B_t]
 
 
 @functools.partial(jax.jit, static_argnames=("s_tile", "b_tile", "t_tile",
@@ -59,83 +49,23 @@ def rhp_project_update(state: jax.Array, syn_idx: jax.Array,
                        s_tile: int = 128, b_tile: int = 128,
                        t_tile: int = 512,
                        interpret: bool = True) -> jax.Array:
-    """state [n, b] f32 += routed sign-row add. syn_idx [T] i32 (-1
-    matches no row), values [T] f32 (mask pre-folded), signs [T, b] f32.
-    All dims must be tile multiples (ops.py pads)."""
+    """state [n, b] f32 += routed sign-row add. syn_idx [1, T] i32 (-1
+    matches no row), values [1, T] f32 (mask pre-folded), signs [T, b]
+    f32. All dims must be tile multiples (ops.py pads)."""
     n, b = state.shape
-    t_total = syn_idx.shape[0]
+    t_total = syn_idx.shape[1]
     grid = (n // s_tile, b // b_tile, t_total // t_tile)
     return pl.pallas_call(
-        functools.partial(_kernel, s_tile=s_tile),
+        functools.partial(_kernel, s_tile=s_tile, t_tile=t_tile),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((s_tile, b_tile), lambda s, b_, t: (s, b_)),
-            pl.BlockSpec((t_tile,), lambda s, b_, t: (t,)),
-            pl.BlockSpec((t_tile,), lambda s, b_, t: (t,)),
+            pl.BlockSpec((1, t_tile), lambda s, b_, t: (0, t)),
+            pl.BlockSpec((1, t_tile), lambda s, b_, t: (0, t)),
             pl.BlockSpec((t_tile, b_tile), lambda s, b_, t: (t, b_)),
+            pl.BlockSpec((s_tile, b_tile), lambda s, b_, t: (s, b_)),
         ],
         out_specs=pl.BlockSpec((s_tile, b_tile), lambda s, b_, t: (s, b_)),
         out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
-        input_output_aliases={0: 0},
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(state, syn_idx, values, signs)
-
-
-def _fused_kernel(state_ref, klo_ref, khi_ref, trw_ref, slo_ref, shi_ref,
-                  val_ref, sgn_ref, out_ref, syn_ref, *, s_tile, t_tile,
-                  n_probe):
-    s = pl.program_id(0)
-    b_ = pl.program_id(1)
-    t = pl.program_id(2)
-
-    @pl.when((s == 0) & (b_ == 0))
-    def _probe():
-        syn_ref[pl.ds(t * t_tile, t_tile)] = probe.probe_rows(
-            klo_ref[...], khi_ref[...], trw_ref[...],
-            slo_ref[...], shi_ref[...], n_probe=n_probe)
-
-    syn = syn_ref[pl.ds(t * t_tile, t_tile)]
-    tile = _tile(syn, val_ref[...], sgn_ref[...], s, s_tile=s_tile)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = state_ref[...] + tile
-
-    @pl.when(t > 0)
-    def _acc():
-        out_ref[...] += tile
-
-
-@functools.partial(jax.jit, static_argnames=("n_probe", "s_tile", "b_tile",
-                                             "t_tile", "interpret"))
-def rhp_probe_update(state: jax.Array, keys_lo: jax.Array,
-                     keys_hi: jax.Array, table_rows: jax.Array,
-                     sid_lo: jax.Array, sid_hi: jax.Array,
-                     values: jax.Array, signs: jax.Array, *, n_probe: int,
-                     s_tile: int = 128, b_tile: int = 128,
-                     t_tile: int = 512,
-                     interpret: bool = True) -> jax.Array:
-    """Fused routing probe + sign-row projection add, one HBM pass."""
-    n, b = state.shape
-    t_total = sid_lo.shape[0]
-    size = keys_lo.shape[0]
-    grid = (n // s_tile, b // b_tile, t_total // t_tile)
-    tbl = lambda: pl.BlockSpec((size,), lambda s, b_, t: (0,))
-    return pl.pallas_call(
-        functools.partial(_fused_kernel, s_tile=s_tile, t_tile=t_tile,
-                          n_probe=n_probe),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((s_tile, b_tile), lambda s, b_, t: (s, b_)),
-            tbl(), tbl(), tbl(),
-            pl.BlockSpec((t_tile,), lambda s, b_, t: (t,)),
-            pl.BlockSpec((t_tile,), lambda s, b_, t: (t,)),
-            pl.BlockSpec((t_tile,), lambda s, b_, t: (t,)),
-            pl.BlockSpec((t_tile, b_tile), lambda s, b_, t: (t, b_)),
-        ],
-        out_specs=pl.BlockSpec((s_tile, b_tile), lambda s, b_, t: (s, b_)),
-        out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((t_total,), jnp.int32)],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(state, keys_lo, keys_hi, table_rows, sid_lo, sid_hi, values, signs)
+    )(syn_idx, values, signs, state)

@@ -19,8 +19,8 @@ def _kernel(re_ref, im_ref, delta_ref, mask_ref, twr_ref, twi_ref,
             out_re_ref, out_im_ref):
     re = re_ref[...]                       # [S_t, F]
     im = im_ref[...]
-    delta = delta_ref[...][:, None]        # [S_t, 1]
-    mask = mask_ref[...][:, None]
+    delta = delta_ref[...]                 # [S_t, 1]
+    mask = mask_ref[...]
     twr = twr_ref[...]                     # [1, F]
     twi = twi_ref[...]
 
@@ -44,8 +44,8 @@ def sliding_dft_step(re: jax.Array, im: jax.Array, delta: jax.Array,
         in_specs=[
             pl.BlockSpec((s_tile, f), lambda i: (i, 0)),
             pl.BlockSpec((s_tile, f), lambda i: (i, 0)),
-            pl.BlockSpec((s_tile,), lambda i: (i,)),
-            pl.BlockSpec((s_tile,), lambda i: (i,)),
+            pl.BlockSpec((s_tile, 1), lambda i: (i, 0)),
+            pl.BlockSpec((s_tile, 1), lambda i: (i, 0)),
             pl.BlockSpec((1, f), lambda i: (0, 0)),
             pl.BlockSpec((1, f), lambda i: (0, 0)),
         ],
@@ -56,4 +56,4 @@ def sliding_dft_step(re: jax.Array, im: jax.Array, delta: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((s, f), jnp.float32),
                    jax.ShapeDtypeStruct((s, f), jnp.float32)],
         interpret=interpret,
-    )(re, im, delta, mask, tw_re[None, :], tw_im[None, :])
+    )(re, im, delta[:, None], mask[:, None], tw_re[None, :], tw_im[None, :])

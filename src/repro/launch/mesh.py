@@ -8,15 +8,26 @@ carries data parallelism + federated synopsis merges over DCN.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
 
 from repro.configs.base import ModelConfig
 from repro.sharding.specs import MeshRules
 
 
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """A device mesh whose axes are all ``AxisType.Auto`` — every mesh of
+    this repo comes from here. ``jax.make_mesh`` defaults to Explicit
+    axes, under which the row-sharded scatter of the blue path cannot
+    resolve its output sharding (``SDE`` refuses such a mesh)."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_federation_mesh(n_sites: int | None = None, *, devices=None):
@@ -28,15 +39,13 @@ def make_federation_mesh(n_sites: int | None = None, *, devices=None):
     production multi-pod mesh, hand ``make_production_mesh(multi_pod=
     True)`` to ``Federation`` instead: the ``pod`` axis plays the site
     role and the federation takes one lead device per pod."""
-    import numpy as np
     devs = list(devices) if devices is not None else jax.devices()
     n = n_sites if n_sites is not None else len(devs)
     if not 1 <= n <= len(devs):
         raise ValueError(
             f"federation mesh needs one device per site: asked for {n} "
             f"sites, have {len(devs)} devices")
-    from jax.sharding import Mesh
-    return Mesh(np.asarray(devs[:n]), ("site",))
+    return make_mesh((n,), ("site",), devices=devs[:n])
 
 
 def try_federation_mesh(n_sites: int, *, devices=None):
@@ -53,8 +62,8 @@ def make_debug_mesh(n_devices: int | None = None):
     """Small mesh over whatever devices exist (tests)."""
     n = n_devices or len(jax.devices())
     if n >= 4:
-        return jax.make_mesh((n // 2, 2), ("data", "model"))
-    return jax.make_mesh((n, 1), ("data", "model"))
+        return make_mesh((n // 2, 2), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 def rules_for(cfg: ModelConfig, *, mode: str = "train") -> MeshRules:

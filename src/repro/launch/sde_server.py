@@ -35,8 +35,15 @@ import json
 import sys
 from typing import IO, Iterable, Optional
 
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.service import SDE, api
 from repro.service.gateway import SynopsisGateway
+
+
+# longest request line the socket server reads: a per-stream build or an
+# ingest batch of a few hundred thousand tuples is megabytes of JSON
+# (asyncio's default stream limit, 64 KiB, would drop the connection)
+MAX_REQUEST_BYTES = 1 << 28
 
 
 def _drain_continuous(sde: SDE, out: IO[str]) -> int:
@@ -208,7 +215,15 @@ async def serve_socket(sde: Optional[SDE] = None,
                 # admission control: no read until a response slot frees,
                 # so a flooding client sees delayed acks (TCP backpressure)
                 await client.admit()
-                line = await reader.readline()
+                try:
+                    line = await reader.readline()
+                except ValueError:   # one line over MAX_REQUEST_BYTES
+                    await write_lines(api.Response(
+                        request_id="", ok=False,
+                        error=f"request line exceeds {MAX_REQUEST_BYTES} "
+                              "bytes").to_json() + "\n")
+                    client.release()
+                    break
                 if not line:
                     client.release()
                     break
@@ -248,7 +263,8 @@ async def serve_socket(sde: Optional[SDE] = None,
             with contextlib.suppress(ConnectionError):
                 writer.close()
 
-    server = await asyncio.start_server(handle_conn, host, port)
+    server = await asyncio.start_server(handle_conn, host, port,
+                                        limit=MAX_REQUEST_BYTES)
     bound = server.sockets[0].getsockname()[1]
     print(f"[sde-server] listening on {host}:{bound}", file=err, flush=True)
     if ready is not None and not ready.done():
@@ -257,12 +273,15 @@ async def serve_socket(sde: Optional[SDE] = None,
         await gw.closed_event.wait()
         await asyncio.sleep(0.05)    # let shutdown acks reach their clients
         server.close()
-        await server.wait_closed()
-        for w in list(writers):      # EOF every idle connection
+        # EOF every idle connection BEFORE waiting on the server: since
+        # Python 3.12 ``wait_closed`` also waits for open connections,
+        # and an idle client would otherwise hold shutdown forever
+        for w in list(writers):
             with contextlib.suppress(ConnectionError):
                 w.close()
         while writers:               # their handlers finish promptly
             await asyncio.sleep(0.01)
+        await server.wait_closed()
     await gw.stop()
     return gw
 
@@ -318,6 +337,7 @@ def main(argv=None):
                          "--checkpoint-dir and replay the --wal tail "
                          "before serving")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
     from repro.service import wal as wal_mod
     if args.recover:
         sde = wal_mod.recover(args.checkpoint_dir, args.wal,

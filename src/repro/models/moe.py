@@ -25,17 +25,6 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from .layers import init_dense, init_mlp, mlp_forward
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    # jax <= 0.4 compat: experimental location, check_vma was check_rep
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _experimental_sm(f, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_rep=check_vma)
-
-
 def init_moe(key, cfg: ModelConfig) -> Dict:
     d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
     ks = jax.random.split(key, 5)
@@ -216,7 +205,7 @@ def moe_ffn_shardmap(x: jax.Array, p: Dict, cfg: ModelConfig, mesh,
     )
     out_specs = (P(batch_spec, None, None),
                  dict(lb_loss=P(), z_loss=P(), expert_load=P(None)))
-    fn = _shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                     out_specs=out_specs, check_vma=False)
     out, aux = fn(x, p["router"], p["wg"], p["wu"], p["wd"],
                   p.get("dense"))
@@ -302,7 +291,7 @@ def moe_ffn_ep_decode(x: jax.Array, p: Dict, cfg: ModelConfig, mesh,
     )
     out_specs = (P(batch_spec, None, None),
                  dict(lb_loss=P(), z_loss=P(), expert_load=P(None)))
-    fn = _shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                     out_specs=out_specs, check_vma=False)
     out, aux = fn(x, p["router"], p["wg"], p["wu"], p["wd"],
                   p.get("dense"))

@@ -54,7 +54,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro import core
 from repro.core import batched, federated
@@ -275,6 +275,11 @@ class SDE:
         if backend is None:
             backend = os.environ.get("SDE_BACKEND", "") or "xla"
         self.backend = backend
+        if mesh is not None and AxisType.Explicit in mesh.axis_types:
+            raise ValueError(
+                f"SDE needs a mesh with Auto axes, got axis types "
+                f"{mesh.axis_types} (jax.make_mesh defaults to Explicit); "
+                "build it with repro.launch.mesh.make_mesh")
         self.mesh = mesh
         if device is not None and mesh is not None:
             raise ValueError(
@@ -1557,18 +1562,17 @@ def _tree_nbytes(tree) -> int:
 
 # ---------------------------------------------------------------------------
 # jitted update/step dispatch, cached per (kind, backend, sharding,
-# has_sources, n_probe, fuse_probe). The cached program is the WHOLE blue
-# path for one kind: hashed routing probe, routed update and data-source
-# update fused into one dispatch; the state buffer is donated (in-place on
-# device), and — on a mesh — pinned to the stack's `synopsis`-axis sharding
-# while the routing-table mirror stays replicated.
+# has_sources, n_probe). The cached program is the WHOLE blue path for one
+# kind: hashed routing probe, routed update and data-source update fused
+# into one dispatch; the state buffer is donated (in-place on device), and
+# — on a mesh — pinned to the stack's `synopsis`-axis sharding while the
+# routing-table mirror stays replicated.
 #
 # Kernel choice is the REGISTRY's, not the engine's: under
 # ``backend="pallas"`` the kind's declared ``update_kernel`` resolves to a
-# fused probe+scatter Pallas program (one HBM pass per batch when
-# ``SDE_FUSED_PROBE`` is on); kinds without a declaration — and the
-# ``backend="xla"`` path — run probe-then-``batched.stacked_update``. The
-# caches are bounded KindCaches: engines evict their kinds' entries on
+# Pallas scatter program fed by the probe; kinds without a declaration —
+# and the ``backend="xla"`` path — run probe-then-``batched.stacked_update``.
+# The caches are bounded KindCaches: engines evict their kinds' entries on
 # stop/close (``kops.KERNEL_CACHE_SIZE`` gauges them).
 # ---------------------------------------------------------------------------
 
@@ -1577,21 +1581,20 @@ _STEP_CACHE = kops.KindCache("step")
 
 
 def _update_fn(kind, backend: str, sharding, has_sources: bool,
-               n_probe: int, fuse_probe: bool):
+               n_probe: int):
     def build():
         name = f"update:{type(kind).__name__}"
-        kernel = (kops.resolve_update_kernel(kind, fuse_probe)
+        kernel = (kops.resolve_update_kernel(kind)
                   if backend == "pallas" else None)
 
         def fused(state, klo, khi, trows, sid_lo, sid_hi, items, vals, msk,
                   *src):
             kops.TRACE_COUNT[name] += 1     # runs only when jit (re)traces
             src_rows = src[0] if has_sources else None
-            if kernel is not None:
-                return kernel(state, klo, khi, trows, sid_lo, sid_hi,
-                              items, vals, msk, src_rows, n_probe=n_probe)
             syn_idx = kops.route_probe(klo, khi, trows, sid_lo, sid_hi,
                                        n_probe=n_probe)   # [-1 => unrouted]
+            if kernel is not None:
+                return kernel(state, syn_idx, items, vals, msk, src_rows)
             return batched.stacked_update(kind, state, syn_idx, items,
                                           vals, msk, src_rows)
 
@@ -1601,14 +1604,13 @@ def _update_fn(kind, backend: str, sharding, has_sources: bool,
         return jax.jit(fused, **kw)
 
     return _UPDATE_CACHE.get(
-        (kind, backend, sharding, has_sources, n_probe, fuse_probe), build)
+        (kind, backend, sharding, has_sources, n_probe), build)
 
 
 def _update(kind, backend, sharding, n_probe, state, klo, khi, trows,
             sid_lo, sid_hi, items, vals, msk, src_rows=None):
     kops.DISPATCH_COUNT[f"update:{type(kind).__name__}"] += 1
-    fn = _update_fn(kind, backend, sharding, src_rows is not None, n_probe,
-                    kops.probe_fusion_enabled())
+    fn = _update_fn(kind, backend, sharding, src_rows is not None, n_probe)
     if src_rows is None:
         return fn(state, klo, khi, trows, sid_lo, sid_hi, items, vals, msk)
     return fn(state, klo, khi, trows, sid_lo, sid_hi, items, vals, msk,

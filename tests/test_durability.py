@@ -156,8 +156,8 @@ _SERVER_SCRIPT = textwrap.dedent("""
     from repro.service import SDE, WriteAheadLog, Checkpointer
     from repro.launch import sde_server
 
-    wal_path, ck_dir, pipelined = (
-        sys.argv[1], sys.argv[2], sys.argv[3] == "1")
+    wal_path, ck_dir, pipelined, kill_after = (
+        sys.argv[1], sys.argv[2], sys.argv[3] == "1", int(sys.argv[4]))
     sde = SDE(pipelined=pipelined)
     wal = WriteAheadLog(wal_path, tag=sde.site)
     ckp = Checkpointer(sde, ck_dir, interval=3, keep=2, rebase_every=4)
@@ -182,6 +182,8 @@ _SERVER_SCRIPT = textwrap.dedent("""
         sde_server.serve_lines([json.dumps(req)], sde, out=devnull,
                                wal=wal, checkpointer=ckp)
         print(f"ACK {i}", flush=True)      # durable: wal.sync() ran
+        if i == kill_after:                # no request races the kill
+            break
     print("DONE", flush=True)
     while True:                            # hold state until SIGKILL
         time.sleep(0.1)
@@ -199,7 +201,7 @@ def test_sigkill_recovery_byte_identical(tmp_path, pipelined):
     env.pop("SDE_PIPELINED", None)
     proc = subprocess.Popen(
         [sys.executable, "-c", _SERVER_SCRIPT, wal_path, ck_dir,
-         "1" if pipelined else "0"],
+         "1" if pipelined else "0", "17"],
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
         env=env)
     try:
@@ -260,6 +262,7 @@ _MESH_SCRIPT = textwrap.dedent("""
     import numpy as np, jax
     from jax.sharding import NamedSharding
     from repro.service import SDE
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.RandomState(0)
     eng = SDE()          # chain written WITHOUT a mesh (1-device layout)
@@ -275,7 +278,7 @@ _MESH_SCRIPT = textwrap.dedent("""
     eng.ingest(sids2, np.ones(512, np.float32))
     assert eng.snapshot(d, 1, incremental=True) == "delta"
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     eng2 = SDE.restore(d, mesh=mesh)     # base + delta, repartitioned
     stack = next(iter(eng2.stacks.values()))
     for leaf in jax.tree.leaves(stack.state):

@@ -219,8 +219,9 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     import numpy as np, jax
     from jax.sharding import NamedSharding
     from repro.service import SDE
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     eng = SDE(mesh=mesh)
     eng.handle({"type": "build", "request_id": "b", "synopsis_id": "cm",
                 "kind": "countmin",
@@ -260,3 +261,18 @@ def test_stacks_sharded_over_synopsis_axis_multidevice():
                          timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK" in out.stdout
+
+
+def test_explicit_axis_mesh_refused_at_construction():
+    """``jax.make_mesh`` defaults to Explicit axes, under which the
+    row-sharded scatter cannot resolve its output sharding: the engine
+    refuses such a mesh up front; ``launch.mesh.make_mesh`` serves."""
+    from repro.launch.mesh import make_mesh
+    explicit = jax.make_mesh((1, 1), ("data", "model"))
+    with pytest.raises(ValueError, match="Auto axes"):
+        SDE(mesh=explicit)
+    eng = SDE(mesh=make_mesh((1, 1), ("data", "model")))
+    assert eng.handle({"type": "build", "request_id": "b",
+                       "synopsis_id": "c", "kind": "countmin",
+                       "per_stream_of_source": True, "n_streams": 4}).ok
+    eng.ingest(np.arange(4, dtype=np.int64), np.ones(4, np.float32))

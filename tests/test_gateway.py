@@ -391,6 +391,43 @@ def test_socket_server_roundtrip(pipelined):
     assert not fut.result().ok
 
 
+def test_socket_server_takes_megabyte_requests_and_idle_clients():
+    """A per-stream build over 65,536 ids is a ~1.3 MB request line; and
+    an idle second connection must not hold shutdown open."""
+    sids = list(range(1, 1 << 16))
+
+    async def drive():
+        ready = asyncio.get_running_loop().create_future()
+        server = asyncio.ensure_future(sde_server.serve_socket(
+            SDE(), port=0, ready=ready, err=io.StringIO()))
+        port = await asyncio.wait_for(ready, 10)
+        idle = await asyncio.open_connection("127.0.0.1", port)
+        reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        reqs = [{"type": "build", "request_id": "b", "synopsis_id": "cm",
+                 "kind": "countmin", "per_stream_of_source": True,
+                 "stream_ids": sids},
+                _ingest("i0", sids[:4096]),
+                {"type": "shutdown", "request_id": "bye"}]
+        writer.write("".join(json.dumps(r) + "\n" for r in reqs).encode())
+        await writer.drain()
+        lines = []
+        while True:
+            line = await asyncio.wait_for(reader.readline(), 30)
+            if not line:
+                break
+            lines.append(json.loads(line))
+        assert await asyncio.wait_for(idle[0].read(), 10) == b""  # EOF
+        idle[1].close()
+        writer.close()
+        await asyncio.wait_for(server, 10)
+        return lines
+
+    by_id = {r["request_id"]: r for r in asyncio.run(drive())}
+    assert by_id["b"]["ok"], by_id["b"]["error"]
+    assert by_id["i0"]["ok"] and by_id["bye"]["ok"]
+    assert by_id["bye"]["value"]["synopses"] == len(sids)
+
+
 # ---------------------------------------------------------------------------
 # shutdown request — engine level and JSON-lines server
 # ---------------------------------------------------------------------------

@@ -1,11 +1,11 @@
 """The update-kernel registry contract (PR 6 tentpole).
 
 Every scatter kind resolves its Pallas kernel by NAME (no isinstance
-dispatch in the engine); fused-probe and probe-then-scatter forms are
+dispatch in the engine); the probe-then-scatter kernels are
 byte-identical to the XLA reference path across all twelve kinds,
 including unrouted ids and data-source rows; the compiled-program caches
 are bounded and release per-kind entries on stop/close; the env
-overrides (SDE_PALLAS_INTERPRET, SDE_FUSED_PROBE) follow their contract.
+overrides (SDE_PALLAS_INTERPRET, SDE_BACKEND) follow their contract.
 """
 import os
 import subprocess
@@ -78,8 +78,7 @@ def test_every_scatter_kind_declares_a_registered_kernel():
         if hasattr(kind, "stacked_add_batch") and not hasattr(kind, "step"):
             assert kname in ops.UPDATE_KERNELS, (
                 f"{name} has a scatter path but no registered kernel")
-            assert callable(ops.resolve_update_kernel(kind, True))
-            assert callable(ops.resolve_update_kernel(kind, False))
+            assert callable(ops.resolve_update_kernel(kind))
         elif kname is None:
             assert ops.resolve_update_kernel(kind) is None
 
@@ -93,7 +92,7 @@ def test_unregistered_kernel_name_raises_with_guidance():
 
 
 def test_register_duplicate_kernel_requires_overwrite():
-    builder = lambda kind, fuse: None
+    builder = lambda kind: None
     ops.register_update_kernel("_test_dup", builder)
     try:
         with pytest.raises(ValueError, match="already registered"):
@@ -104,7 +103,7 @@ def test_register_duplicate_kernel_requires_overwrite():
 
 
 # ---------------------------------------------------------------------------
-# registry-level equivalence: fused probe == probe-then-scatter == XLA
+# registry-level equivalence: probe-then-scatter kernel == XLA
 # ---------------------------------------------------------------------------
 _SCATTER_KINDS = [
     core.CountMin(eps=0.1, delta=0.1, weighted=False),
@@ -137,16 +136,12 @@ def test_registry_kernel_matches_xla_reference(kind):
     src = jnp.asarray([1, 5], jnp.int32)     # data-source rows
 
     state = batched.stacked_init(kind, n)
-    outs = {}
-    for fuse in (True, False):
-        fn = ops.resolve_update_kernel(kind, fuse)
-        outs[fuse] = np.asarray(fn(state, klo, khi, trows, slo, shi,
-                                   items, vals, msk, src, n_probe=n_probe))
     rows = ops.route_probe(klo, khi, trows, slo, shi, n_probe=n_probe)
+    got = np.asarray(ops.resolve_update_kernel(kind)(
+        state, rows, items, vals, msk, src))
     want = np.asarray(batched.stacked_update(kind, state, rows, items,
                                              vals, msk, src))
-    assert np.array_equal(outs[True], want), "fused probe diverged"
-    assert np.array_equal(outs[False], want), "unfused kernel diverged"
+    assert np.array_equal(got, want), "pallas kernel diverged"
 
 
 # ---------------------------------------------------------------------------
@@ -265,13 +260,15 @@ def test_pallas_interpret_env_override(monkeypatch):
     assert ops._interpret() is (jax.default_backend() != "tpu")
 
 
-def test_fused_probe_env_toggle(monkeypatch):
-    monkeypatch.delenv("SDE_FUSED_PROBE", raising=False)
-    assert ops.probe_fusion_enabled() is True     # fused by default
-    monkeypatch.setenv("SDE_FUSED_PROBE", "0")
-    assert ops.probe_fusion_enabled() is False
-    monkeypatch.setenv("SDE_FUSED_PROBE", "1")
-    assert ops.probe_fusion_enabled() is True
+def test_pallas_interpret_refused_on_tpu_backend(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("SDE_PALLAS_INTERPRET", raising=False)
+    assert ops._interpret() is False          # never interpreted on TPU
+    monkeypatch.setenv("SDE_PALLAS_INTERPRET", "0")
+    assert ops._interpret() is False
+    monkeypatch.setenv("SDE_PALLAS_INTERPRET", "1")
+    with pytest.raises(ValueError, match="TPU backend"):
+        ops._interpret()
 
 
 def test_backend_env_default(monkeypatch):
@@ -290,10 +287,11 @@ _PALLAS_SHARDED_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax
     from repro.service import SDE
+    from repro.launch.mesh import make_mesh
 
     states = {}
     for backend in ("xla", "pallas"):
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         eng = SDE(backend=backend, mesh=mesh)
         r = eng.handle({"type": "build", "request_id": "b",
                         "synopsis_id": "cm", "kind": "countmin",

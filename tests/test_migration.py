@@ -356,9 +356,10 @@ _MESH_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax
     from repro.service import SDE
+    from repro.launch.mesh import make_mesh
 
     def mk():
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         eng = SDE(mesh=mesh)
         r = eng.handle({"type": "build", "request_id": "b",
                         "synopsis_id": "pt", "kind": "countmin",

@@ -300,9 +300,10 @@ _MESH_SCRIPT = textwrap.dedent("""
     import numpy as np, jax
     from jax.sharding import NamedSharding
     from repro.service import SDE, api
+    from repro.launch.mesh import make_mesh
     from repro.kernels import ops as kops
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     eng = SDE(mesh=mesh)
     eng.handle({"type": "build", "request_id": "b", "synopsis_id": "cm",
                 "kind": "countmin",

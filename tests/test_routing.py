@@ -388,6 +388,7 @@ _RESTORE_MESH_SCRIPT = textwrap.dedent("""
     import numpy as np, jax
     from jax.sharding import NamedSharding
     from repro.service import SDE
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.RandomState(0)
     sid_pop = np.unique(rng.randint(0, 2**63 - 1, 96, dtype=np.int64))
@@ -404,7 +405,7 @@ _RESTORE_MESH_SCRIPT = textwrap.dedent("""
 
     # restore onto an 8-device mesh: state rows shard over `synopsis`,
     # the routing-table mirror replicates
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     eng2 = SDE.restore(d, mesh=mesh)
     stack = next(iter(eng2.stacks.values()))
     for leaf in jax.tree.leaves(stack.state):
