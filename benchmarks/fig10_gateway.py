@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.kernels import ops as kops
+from repro import tracing
 from repro.service import SDE, SynopsisGateway
 from .common import time_fn, csv_row
 
@@ -80,11 +80,11 @@ def run(full: bool = False, check: bool = False):
     t_gateway = time_fn(gateway_tick, warmup=1, iters=5)
 
     # probe the invariant on one extra tick: 64 clients, ONE dispatch
-    d0 = kops.DISPATCH_COUNT.get("update:CountMin", 0)
-    c0 = kops.GATEWAY_COALESCED.get("ingest", 0)
+    d0 = tracing.DISPATCH_COUNT.get("update:CountMin", 0)
+    c0 = tracing.GATEWAY_COALESCED.get("ingest", 0)
     gateway_tick()
-    dispatches = kops.DISPATCH_COUNT["update:CountMin"] - d0
-    coalesced = kops.GATEWAY_COALESCED["ingest"] - c0
+    dispatches = tracing.DISPATCH_COUNT["update:CountMin"] - d0
+    coalesced = tracing.GATEWAY_COALESCED["ingest"] - c0
 
     tuples = _N_CLIENTS * _TUPLES_PER_CLIENT
     speedup = t_serial / t_gateway

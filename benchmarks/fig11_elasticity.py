@@ -29,7 +29,7 @@ import time
 
 import numpy as np
 
-from repro.kernels import ops as kops
+from repro import tracing
 from repro.service import SDE, Reconciler, worst_fit_decreasing
 from .common import csv_row
 
@@ -158,7 +158,7 @@ def run(full: bool = False, check: bool = False):
                              ("optimal", "opt"))}
     rec_vs_opt = state["cost_rec"] / state["cost_opt"]
     static_vs_opt = state["cost_static"] / state["cost_opt"]
-    migrated = int(kops.MIGRATED_ROWS[eng.site])
+    migrated = int(tracing.MIGRATED_ROWS[eng.site])
     rows = [csv_row(
         f"fig11_elasticity_w{_W}_s{_N_STREAMS}",
         state["t_reconcile"] / state["n_chunks"],

@@ -29,7 +29,7 @@ import time
 
 import numpy as np
 
-from repro.kernels import ops as kops
+from repro import tracing
 from repro.service import SDE
 from .common import csv_row
 
@@ -149,15 +149,15 @@ def run(full: bool = False, check: bool = False):
         # measured through the CHECKPOINT_BYTES probe
         eng = engines["incr"]
         ck = f"{tmp}/incr"
-        b0 = kops.CHECKPOINT_BYTES[eng.site]
+        b0 = tracing.CHECKPOINT_BYTES[eng.site]
         eng.snapshot(ck, next_step(), incremental=False)
-        bytes_full = kops.CHECKPOINT_BYTES[eng.site] - b0
+        bytes_full = tracing.CHECKPOINT_BYTES[eng.site] - b0
         for sids, vals in _interval_traffic(rng, 0):
             eng.ingest(sids, vals)
-        b0 = kops.CHECKPOINT_BYTES[eng.site]
+        b0 = tracing.CHECKPOINT_BYTES[eng.site]
         eng.snapshot(ck, next_step(), incremental=True)
-        bytes_delta = kops.CHECKPOINT_BYTES[eng.site] - b0
-        dirty = int(kops.DIRTY_ROWS[eng.site])
+        bytes_delta = tracing.CHECKPOINT_BYTES[eng.site] - b0
+        dirty = int(tracing.DIRTY_ROWS[eng.site])
         for e in engines.values():
             e.close()
 

@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.kernels import ops as kops
+from repro import tracing
 from repro.service import SDE, api
 from .common import csv_row, time_fn
 
@@ -101,9 +101,9 @@ def run(full: bool = False, check: bool = False):
     assert abs(est_sub - est_scan) <= tol, \
         f"subpop {est_sub} vs scan {est_scan} (tol {tol})"
 
-    before = int(kops.DISPATCH_COUNT["CountMin"])
+    before = int(tracing.DISPATCH_COUNT["CountMin"])
     subpop()
-    n_disp = int(kops.DISPATCH_COUNT["CountMin"]) - before
+    n_disp = int(tracing.DISPATCH_COUNT["CountMin"]) - before
 
     iters = 10 if full else 3
     t_sub = time_fn(subpop, warmup=1, iters=iters)

@@ -420,7 +420,7 @@ def same_image(a: dict, b: dict) -> bool:
 
 
 async def _serve_and_drive(dep: Deployment, seed: int, run_dir: pathlib.Path):
-    from repro.kernels import ops as kops
+    from repro import tracing
     from repro.launch import sde_server
     from repro.service import SDE, wal as wal_mod
 
@@ -449,7 +449,7 @@ async def _serve_and_drive(dep: Deployment, seed: int, run_dir: pathlib.Path):
         synopses=len(sde.entries))
 
     per_client = dep.window // dep.n_clients
-    trace0 = sum(kops.TRACE_COUNT.values())
+    trace0 = sum(tracing.TRACE_COUNT.values())
     t0 = clock()
     for _ in range(dep.n_windows):
         sids, vals = tr.window()
@@ -467,7 +467,7 @@ async def _serve_and_drive(dep: Deployment, seed: int, run_dir: pathlib.Path):
     n_batches = sde.batches_ingested
     log(phase="ingest", **since(t0), windows=dep.n_windows,
         events=dep.window * dep.n_windows, batches=n_batches,
-        traces=sum(kops.TRACE_COUNT.values()) - trace0,
+        traces=sum(tracing.TRACE_COUNT.values()) - trace0,
         state_bytes=sde.memory_bytes(), checkpoints=checkpointer.snapshots)
 
     tr.finish(n_levels=len(sde.multidim["md"].levels))

@@ -17,7 +17,6 @@ fall back to ``batched.stacked_update`` in the engine.
 """
 from __future__ import annotations
 
-import collections
 import logging
 import os
 from typing import Any, Callable, Dict
@@ -28,6 +27,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import batched, federated, hashing
+# the counters this module's code bumps; ``GATEWAY_TICKS`` is re-exported
+# too, under the name benchmarks read it by (``kops.GATEWAY_TICKS``)
+from repro.tracing import (DISPATCH_COUNT, GATEWAY_TICKS,  # noqa: F401
+                           KERNEL_CACHE_SIZE, TRACE_COUNT, named)
 from . import (max_scatter, onehot_matmul, rhp_project, sliding_dft,
                pairwise_corr as pc)
 
@@ -146,122 +149,10 @@ def _source_fold(out: jax.Array, idx: jax.Array, contrib: jax.Array,
 # ad-hoc/continuous queries against that kind's stack: per-row estimates are
 # computed where the rows live (the [capacity] axis stays `synopsis`-sharded
 # inside the program, so no state gather crosses the mesh) and only the tiny
-# estimate vectors are replicated to the host via ``out_shardings``.
-#
-# ``TRACE_COUNT`` increments at trace time only and ``DISPATCH_COUNT`` on
-# every call — tests use them to assert "one dispatch, one compiled program
-# per kind per query-batch shape". ``KERNEL_CACHE_SIZE`` gauges how many
-# compiled entries each KindCache holds (the caches are BOUNDED: engines
-# evict their kinds' entries on stop/close instead of growing forever).
+# estimate vectors are replicated to the host via ``out_shardings``. Each
+# program is named by its kind (``estimate_all_CountMin``, ...), so a device
+# trace says which kind it answered. Its counters live in ``repro.tracing``.
 # ---------------------------------------------------------------------------
-
-TRACE_COUNT: collections.Counter = collections.Counter()
-DISPATCH_COUNT: collections.Counter = collections.Counter()
-KERNEL_CACHE_SIZE: collections.Counter = collections.Counter()
-
-# Blue-path pipeline probes: the engine's bounded ingest queue
-# (service/pipeline.py) reports how many dispatched-but-unmaterialized
-# batches are in flight, keyed by engine site. ``PIPELINE_IN_FLIGHT`` is
-# the current gauge, ``PIPELINE_MAX_IN_FLIGHT`` the high-water mark —
-# tests and benchmarks assert batches actually overlap (depth reached)
-# and that fences drain back to zero, without reaching into internals.
-PIPELINE_IN_FLIGHT: collections.Counter = collections.Counter()
-PIPELINE_MAX_IN_FLIGHT: collections.Counter = collections.Counter()
-
-
-def note_in_flight(tag: str, depth: int) -> None:
-    """Record a pipeline's current in-flight batch depth."""
-    PIPELINE_IN_FLIGHT[tag] = depth
-    if depth > PIPELINE_MAX_IN_FLIGHT[tag]:
-        PIPELINE_MAX_IN_FLIGHT[tag] = depth
-
-
-# Gateway coalescing probes: the multi-client micro-batcher
-# (service/gateway.py) reports how many client requests each fused
-# dispatch absorbed. ``GATEWAY_TICKS`` counts micro-batcher ticks per
-# gateway tag; ``GATEWAY_COALESCED`` counts client requests folded into
-# coalesced engine calls, keyed by request class ("ingest" / "query").
-# Tests pair these with TRACE_COUNT/DISPATCH_COUNT to assert that N
-# concurrent clients cost ONE blue-path dispatch per kind per tick —
-# serving cost scales with tick count, not client count.
-GATEWAY_TICKS: collections.Counter = collections.Counter()
-GATEWAY_COALESCED: collections.Counter = collections.Counter()
-
-
-def note_coalesced(klass: str, n: int) -> None:
-    """Record ``n`` client requests coalesced into one engine call."""
-    GATEWAY_COALESCED[klass] += n
-
-
-# Elasticity probes: the reconciler (service/reconciler.py) and the
-# migration plane (service/migration.py via SDE.migrate_rows /
-# implant_synopses) report the control loop's work. ``RECONCILE_COUNT``
-# counts reconcile passes per tag (engine site or federation),
-# ``MIGRATED_ROWS`` totals rows moved by the plane per engine site, and
-# ``REBALANCE_IMBALANCE`` gauges the latest max/mean worker-load ratio a
-# reconcile observed (1.0 = perfectly balanced). All three surface
-# through ``SDE._status`` into the JSON status response.
-RECONCILE_COUNT: collections.Counter = collections.Counter()
-MIGRATED_ROWS: collections.Counter = collections.Counter()
-REBALANCE_IMBALANCE: collections.Counter = collections.Counter()
-
-
-def note_migrated(site: str, n_rows: int) -> None:
-    """Record ``n_rows`` rows moved by the migration plane."""
-    MIGRATED_ROWS[site] += n_rows
-
-
-def note_reconcile(tag: str, imbalance: float) -> None:
-    """Record one reconcile pass and the imbalance it measured."""
-    RECONCILE_COUNT[tag] += 1
-    REBALANCE_IMBALANCE[tag] = float(imbalance)
-
-
-# Durability probes: the checkpoint path (SDE.snapshot) and the
-# write-ahead ingest log (service/wal.py) report what persistence ships.
-# ``CHECKPOINT_BYTES`` accumulates bytes handed to ``checkpoint.save``
-# per engine site — benchmarks diff it around one save to compare a
-# dirty-row delta against a full snapshot (the fig12 byte gate).
-# ``DIRTY_ROWS`` gauges the row count the LATEST snapshot shipped (full:
-# every capacity row; delta: only rows touched since the previous
-# snapshot). ``WAL_APPENDS`` counts records appended to the write-ahead
-# log per tag. All three surface through ``SDE._status``.
-CHECKPOINT_BYTES: collections.Counter = collections.Counter()
-DIRTY_ROWS: collections.Counter = collections.Counter()
-WAL_APPENDS: collections.Counter = collections.Counter()
-
-
-def note_checkpoint(site: str, n_bytes: int, n_rows: int) -> None:
-    """Record one snapshot: bytes shipped (cumulative) and rows shipped
-    (latest-snapshot gauge)."""
-    CHECKPOINT_BYTES[site] += int(n_bytes)
-    DIRTY_ROWS[site] = int(n_rows)
-
-
-def note_wal_append(tag: str, n: int = 1) -> None:
-    """Record ``n`` records appended to a write-ahead log."""
-    WAL_APPENDS[tag] += n
-
-
-# Subpopulation / outlier-workflow probes: ``SUBPOP_COVER_KEYS``
-# accumulates how many covering-set group keys ``subpop_query`` merged
-# per engine site — paired with DISPATCH_COUNT it pins "K maintained
-# groups answered in ONE fused dispatch". ``OUTLIER_EMITS`` counts
-# flagged subpopulations the continuous outlier workflow emitted per
-# site; tests also use it (with the entry counters) to pin that the
-# workflow rides the ALREADY-maintained synopses — zero extra builds.
-SUBPOP_COVER_KEYS: collections.Counter = collections.Counter()
-OUTLIER_EMITS: collections.Counter = collections.Counter()
-
-
-def note_subpop(site: str, n_keys: int) -> None:
-    """Record one subpop query's covering-set size."""
-    SUBPOP_COVER_KEYS[site] += int(n_keys)
-
-
-def note_outlier(site: str, n_flagged: int) -> None:
-    """Record flagged subpopulations emitted by an outlier tick."""
-    OUTLIER_EMITS[site] += int(n_flagged)
 
 
 _KIND_CACHES: list["KindCache"] = []
@@ -336,7 +227,7 @@ def _estimate_all_fn(kind, out_sharding):
         kw = {}
         if out_sharding is not None:
             kw["out_shardings"] = out_sharding
-        return jax.jit(program, **kw)
+        return jax.jit(named(program, f"estimate_all_{name}"), **kw)
 
     return _ESTIMATE_ALL.get((kind, out_sharding), build)
 
@@ -364,7 +255,7 @@ def _estimate_merged_fn(kind):
             return batched.stacked_estimate(
                 kind, one, jnp.zeros((1,), jnp.int32), *query_args)
 
-        return jax.jit(program)
+        return jax.jit(named(program, f"estimate_merged_{name}"))
 
     return _ESTIMATE_MERGED.get((kind,), build)
 
@@ -384,7 +275,7 @@ def _estimate_subpop_fn(kind, n_rows, out_sharding):
         kw = {}
         if out_sharding is not None:
             kw["out_shardings"] = out_sharding
-        return jax.jit(program, **kw)
+        return jax.jit(named(program, f"estimate_subpop_{name}"), **kw)
 
     return _ESTIMATE_SUBPOP.get((kind, n_rows, out_sharding), build)
 
@@ -434,7 +325,7 @@ def _estimate_collective_fn(kind, mesh, axis_name):
                             out_specs=P(), check_vma=False)
             return fn(states, *query_args)
 
-        return jax.jit(program)
+        return jax.jit(named(program, f"estimate_collective_{name}"))
 
     return _ESTIMATE_COLLECTIVE.get((kind, mesh, axis_name), build)
 

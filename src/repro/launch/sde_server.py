@@ -35,6 +35,7 @@ import json
 import sys
 from typing import IO, Iterable, Optional
 
+from repro import tracing
 from repro.launch.compile_cache import enable_compilation_cache
 from repro.service import SDE, api
 from repro.service.gateway import SynopsisGateway
@@ -170,7 +171,9 @@ async def serve_socket(sde: Optional[SDE] = None,
     ``reconciler`` rides the gateway tick; so do the durability hooks —
     ``wal`` (fsynced once per tick, before its acks go out) and
     ``checkpointer`` (incremental snapshot every N ingested batches).
-    Returns the gateway (engine closed, probes/commit log intact)."""
+    Returns the gateway (engine closed, probes/commit log intact). With
+    a profiler session running, each request line's JSON decode and each
+    ack's encode and write are spans (``repro.tracing``)."""
     gw = SynopsisGateway(sde, tick_interval=tick_interval,
                          max_in_flight=max_in_flight,
                          client_log_cap=client_log_cap,
@@ -194,7 +197,9 @@ async def serve_socket(sde: Optional[SDE] = None,
 
         async def finish(fut) -> None:
             try:
-                await write_lines((await fut).to_json() + "\n")
+                resp = await fut
+                with tracing.span("frontend.ack"):
+                    await write_lines(resp.to_json() + "\n")
             except (ConnectionError, RuntimeError):
                 pass                 # client gone mid-ack
             finally:
@@ -232,7 +237,8 @@ async def serve_socket(sde: Optional[SDE] = None,
                     client.release()
                     continue
                 try:
-                    req = json.loads(line)
+                    with tracing.span("frontend.decode", bytes=len(line)):
+                        req = json.loads(line)
                     if not isinstance(req, dict):
                         raise ValueError("request must be a JSON object")
                 except Exception as e:  # noqa: BLE001 - report, keep serving

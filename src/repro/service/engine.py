@@ -56,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro import core
+from repro import core, tracing
 from repro.core import batched, federated
 from repro.core.multidim import MultidimSpec
 from repro.core.synopsis import Synopsis, kind_params
@@ -489,43 +489,47 @@ class SDE:
         queries are grouped by kind, their args batched into padded device
         arrays, and each group reads the `synopsis`-sharded stack state in
         place — no per-query host round trip."""
-        # fence: pending continuous batches were dispatched against
-        # earlier state; they retire before ad-hoc reads answer, so the
-        # response stream stays in ingest order
-        self.flush()
-        responses: List[Optional[api.Response]] = [None] * len(requests)
-        groups: Dict[Any, List[int]] = {}
-        for i, req in enumerate(requests):
-            e = self.entries.get(req.synopsis_id)
-            if e is None:
-                responses[i] = api.Response(
-                    request_id=req.request_id, ok=False,
-                    error=f"unknown synopsis {req.synopsis_id!r}")
-            elif req.query is not None and not isinstance(req.query, dict):
-                # fails alone — never poisons the rest of the batch
-                responses[i] = api.Response(
-                    request_id=req.request_id, ok=False,
-                    error="query must be an object, got "
-                          f"{type(req.query).__name__}")
-            else:
-                groups.setdefault(e.kind_key, []).append(i)
-        for kind, idxs in groups.items():
-            stack = self.stacks[kind]
-            rows = [self.entries[requests[i].synopsis_id].row for i in idxs]
-            vals, errs = self._estimate_rows(
-                kind, stack, rows, [requests[i].query or {} for i in idxs])
-            for i, val, err in zip(idxs, vals, errs):
-                if err is not None:
+        with tracing.span("sde.query_many", queries=len(requests)):
+            # fence: pending continuous batches were dispatched against
+            # earlier state; they retire before ad-hoc reads answer, so the
+            # response stream stays in ingest order
+            self.flush()
+            responses: List[Optional[api.Response]] = [None] * len(requests)
+            groups: Dict[Any, List[int]] = {}
+            for i, req in enumerate(requests):
+                e = self.entries.get(req.synopsis_id)
+                if e is None:
                     responses[i] = api.Response(
-                        request_id=requests[i].request_id,
-                        synopsis_id=requests[i].synopsis_id,
-                        ok=False, error=err)
+                        request_id=req.request_id, ok=False,
+                        error=f"unknown synopsis {req.synopsis_id!r}")
+                elif (req.query is not None
+                      and not isinstance(req.query, dict)):
+                    # fails alone — never poisons the rest of the batch
+                    responses[i] = api.Response(
+                        request_id=req.request_id, ok=False,
+                        error="query must be an object, got "
+                              f"{type(req.query).__name__}")
                 else:
-                    responses[i] = api.Response(
-                        request_id=requests[i].request_id,
-                        synopsis_id=requests[i].synopsis_id, value=val,
-                        params=kind_params(kind))
-        return responses
+                    groups.setdefault(e.kind_key, []).append(i)
+            for kind, idxs in groups.items():
+                stack = self.stacks[kind]
+                rows = [self.entries[requests[i].synopsis_id].row
+                        for i in idxs]
+                vals, errs = self._estimate_rows(
+                    kind, stack, rows,
+                    [requests[i].query or {} for i in idxs])
+                for i, val, err in zip(idxs, vals, errs):
+                    if err is not None:
+                        responses[i] = api.Response(
+                            request_id=requests[i].request_id,
+                            synopsis_id=requests[i].synopsis_id,
+                            ok=False, error=err)
+                    else:
+                        responses[i] = api.Response(
+                            request_id=requests[i].request_id,
+                            synopsis_id=requests[i].synopsis_id, value=val,
+                            params=kind_params(kind))
+            return responses
 
     def _query_many_req(self, req: api.QueryMany) -> api.Response:
         subs: List[Optional[api.AdHocQuery]] = []
@@ -669,7 +673,7 @@ class SDE:
         rows = jnp.asarray(np.asarray([e.row for e in entries], np.int32))
         out = kops.estimate_subpop(kind, stack.state, rows, *args,
                                    out_sharding=stack.out_sharding())
-        kops.note_subpop(self.site, len(keys))
+        tracing.note_subpop(self.site, len(keys))
         return api.Response(
             request_id=req.request_id, synopsis_id=req.synopsis_id,
             value=take(jax.tree.map(np.asarray, out), 0),
@@ -791,15 +795,15 @@ class SDE:
             request_id=req.request_id, value=info,
             params=dict(
                 site=self.site,
-                reconcile_count=int(kops.RECONCILE_COUNT[self.site]),
-                migrated_rows=int(kops.MIGRATED_ROWS[self.site]),
+                reconcile_count=int(tracing.RECONCILE_COUNT[self.site]),
+                migrated_rows=int(tracing.MIGRATED_ROWS[self.site]),
                 rebalance_imbalance=float(
-                    kops.REBALANCE_IMBALANCE[self.site]),
-                checkpoint_bytes=int(kops.CHECKPOINT_BYTES[self.site]),
-                dirty_rows=int(kops.DIRTY_ROWS[self.site]),
-                wal_appends=int(kops.WAL_APPENDS[self.site]),
-                subpop_cover_keys=int(kops.SUBPOP_COVER_KEYS[self.site]),
-                outlier_emits=int(kops.OUTLIER_EMITS[self.site])))
+                    tracing.REBALANCE_IMBALANCE[self.site]),
+                checkpoint_bytes=int(tracing.CHECKPOINT_BYTES[self.site]),
+                dirty_rows=int(tracing.DIRTY_ROWS[self.site]),
+                wal_appends=int(tracing.WAL_APPENDS[self.site]),
+                subpop_cover_keys=int(tracing.SUBPOP_COVER_KEYS[self.site]),
+                outlier_emits=int(tracing.OUTLIER_EMITS[self.site])))
 
     # ------------------------------------------------------------------
     # blue path: data
@@ -826,61 +830,71 @@ class SDE:
         engines materialize those responses before returning; pipelined
         engines park them on the bounded queue and return immediately
         (see ``flush``)."""
-        sid_arr = np.asarray(stream_ids)
-        # np.asarray(values, float32) is a NO-OP when the caller already
-        # hands in float32 (the hot path) — .astype would always copy
-        vals_np = np.asarray(values, np.float32)
-        if len(vals_np) != len(sid_arr):
-            raise ValueError(
-                f"ingest batch mismatch: {len(sid_arr)} stream_ids vs "
-                f"{len(vals_np)} values — the two must align 1:1")
-        t = len(sid_arr)
-        if mask is None:
-            mask = np.ones(t, bool)
-        else:
-            mask = np.asarray(mask, bool)
-            if len(mask) != t:
+        with tracing.span("sde.ingest", events=len(stream_ids),
+                          multidim=items is not None):
+            sid_arr = np.asarray(stream_ids)
+            # np.asarray(values, float32) is a NO-OP when the caller
+            # already hands in float32 (the hot path) — .astype would
+            # always copy
+            vals_np = np.asarray(values, np.float32)
+            if len(vals_np) != len(sid_arr):
                 raise ValueError(
-                    f"ingest batch mismatch: {t} stream_ids vs "
-                    f"{len(mask)} mask entries — the two must align 1:1")
-        items64 = None
-        if items is not None:
-            items64 = np.asarray(items, np.int64)
-            if len(items64) != t:
-                raise ValueError(
-                    f"ingest batch mismatch: {t} stream_ids vs "
-                    f"{len(items64)} items — the two must align 1:1")
-        sid64 = sid_arr.astype(np.int64)
-        mask = mask & (sid64 >= 0)
-        self.tuples_ingested += int(mask.sum())
-        self.batches_ingested += 1
-        # deferred dirty tracking: park this batch's surviving ids; the
-        # window resolves to rows in one vectorized lookup per stack
-        # (data-source rows absorb every batch, so an all-unroutable
-        # batch still has to be parked to mark them)
-        self._pending_dirty.append(sid64[mask])
-        if len(self._pending_dirty) >= _DIRTY_RESOLVE_EVERY:
-            self._resolve_dirty()
-        batch_id = self.batches_ingested
-        lo, hi = routing.split64(sid64)
-        sid_lo = jnp.asarray(lo)
-        sid_hi = jnp.asarray(hi)
-        items = jnp.asarray(routing.fold64(
-            sid64 if items64 is None else items64))
-        vals = jnp.asarray(vals_np)
-        msk = jnp.asarray(mask)
-        for kind, stack in self.stacks.items():
-            if stack.is_timeseries:
-                self._ingest_timeseries(stack, sid_lo, sid_hi, vals, msk)
+                    f"ingest batch mismatch: {len(sid_arr)} stream_ids vs "
+                    f"{len(vals_np)} values — the two must align 1:1")
+            t = len(sid_arr)
+            if mask is None:
+                mask = np.ones(t, bool)
             else:
-                self._ingest_stack(stack, sid_lo, sid_hi, items, vals, msk)
-        pending = self._dispatch_continuous(batch_id)
-        if pending is not None:
-            if self._pipeline is not None:
-                self._pipeline.submit(pending)
-            else:
-                self._retire_batch(pending)
-        return batch_id
+                mask = np.asarray(mask, bool)
+                if len(mask) != t:
+                    raise ValueError(
+                        f"ingest batch mismatch: {t} stream_ids vs "
+                        f"{len(mask)} mask entries — the two must align "
+                        "1:1")
+            items64 = None
+            if items is not None:
+                items64 = np.asarray(items, np.int64)
+                if len(items64) != t:
+                    raise ValueError(
+                        f"ingest batch mismatch: {t} stream_ids vs "
+                        f"{len(items64)} items — the two must align 1:1")
+            sid64 = sid_arr.astype(np.int64)
+            mask = mask & (sid64 >= 0)
+            self.tuples_ingested += int(mask.sum())
+            self.batches_ingested += 1
+            # deferred dirty tracking: park this batch's surviving ids;
+            # the window resolves to rows in one vectorized lookup per
+            # stack (data-source rows absorb every batch, so an
+            # all-unroutable batch still has to be parked to mark them)
+            self._pending_dirty.append(sid64[mask])
+            if len(self._pending_dirty) >= _DIRTY_RESOLVE_EVERY:
+                self._resolve_dirty()
+            batch_id = self.batches_ingested
+            lo, hi = routing.split64(sid64)
+            items_np = routing.fold64(sid64 if items64 is None else items64)
+            with tracing.span("ingest.h2d", bytes=lo.nbytes + hi.nbytes
+                              + items_np.nbytes + vals_np.nbytes
+                              + mask.nbytes):
+                sid_lo = jnp.asarray(lo)
+                sid_hi = jnp.asarray(hi)
+                items = jnp.asarray(items_np)
+                vals = jnp.asarray(vals_np)
+                msk = jnp.asarray(mask)
+            for kind, stack in self.stacks.items():
+                with tracing.span("ingest.update", kind=type(kind).__name__):
+                    if stack.is_timeseries:
+                        self._ingest_timeseries(stack, sid_lo, sid_hi, vals,
+                                                msk)
+                    else:
+                        self._ingest_stack(stack, sid_lo, sid_hi, items,
+                                           vals, msk)
+            pending = self._dispatch_continuous(batch_id)
+            if pending is not None:
+                if self._pipeline is not None:
+                    self._pipeline.submit(pending)
+                else:
+                    self._retire_batch(pending)
+            return batch_id
 
     def flush(self) -> int:
         """Pipeline barrier: materialize every pending continuous batch
@@ -980,22 +994,23 @@ class SDE:
         """Materialize one batch's continuous outputs (the only
         device→host sync of the blue path) into ``continuous_out``,
         then score the batch's outlier ticks (``ow/<wf>/<batch>``)."""
-        for ids, take, out in pending.emissions:
-            out = jax.tree.map(np.asarray, out)
-            for i, sid in enumerate(ids):
+        with tracing.span("sde.retire"):
+            for ids, take, out in pending.emissions:
+                out = jax.tree.map(np.asarray, out)
+                for i, sid in enumerate(ids):
+                    self.continuous_out.append(api.Response(
+                        request_id=f"cq/{sid}/{pending.batch_id}",
+                        synopsis_id=sid, value=take(out, i)))
+            for plan, out in pending.extras:
+                out = jax.tree.map(np.asarray, out)
+                ests = [plan.take(out, i)
+                        for i in range(len(plan.assignments) + 1)]
+                payload = outliers.evaluate_tick(plan, ests)
+                tracing.note_outlier(self.site, len(payload["outliers"]))
                 self.continuous_out.append(api.Response(
-                    request_id=f"cq/{sid}/{pending.batch_id}",
-                    synopsis_id=sid, value=take(out, i)))
-        for plan, out in pending.extras:
-            out = jax.tree.map(np.asarray, out)
-            ests = [plan.take(out, i)
-                    for i in range(len(plan.assignments) + 1)]
-            payload = outliers.evaluate_tick(plan, ests)
-            kops.note_outlier(self.site, len(payload["outliers"]))
-            self.continuous_out.append(api.Response(
-                request_id=(f"ow/{plan.workflow.workflow_id}"
-                            f"/{pending.batch_id}"),
-                synopsis_id=plan.workflow.workflow_id, value=payload))
+                    request_id=(f"ow/{plan.workflow.workflow_id}"
+                                f"/{pending.batch_id}"),
+                    synopsis_id=plan.workflow.workflow_id, value=payload))
 
     def _plan_continuous(self) -> Dict[Any, Any]:
         by_kind: Dict[Any, List[Any]] = {}
@@ -1062,7 +1077,7 @@ class SDE:
             if e.kind_key == kind and e.row in mapping:
                 e.row = mapping[e.row]
         self._invalidate_plans()
-        kops.note_migrated(self.site, len(mapping))
+        tracing.note_migrated(self.site, len(mapping))
         return len(mapping)
 
     def resize_stack(self, kind: Any, new_capacity: int) -> int:
@@ -1173,7 +1188,7 @@ class SDE:
                 self.entries[m["synopsis_id"]] = _Entry(
                     kind_key=kind, row=row, **m)
             n += len(metas)
-            kops.note_migrated(self.site, len(metas))
+            tracing.note_migrated(self.site, len(metas))
         self._invalidate_plans()
         return n
 
@@ -1294,7 +1309,7 @@ class SDE:
         self._ckpt_chain.append(step)
         for k in kinds:
             self.stacks[k].dirty.clear()
-        kops.note_checkpoint(self.site, _tree_nbytes(arrays), n_rows)
+        tracing.note_checkpoint(self.site, _tree_nbytes(arrays), n_rows)
         return "delta"
 
     def _snapshot_full(self, directory: str, step: int, *,
@@ -1324,7 +1339,7 @@ class SDE:
         for k in kinds:
             self.stacks[k].dirty.clear()
             n_rows += self.stacks[k].capacity
-        kops.note_checkpoint(self.site, _tree_nbytes(arrays), n_rows)
+        tracing.note_checkpoint(self.site, _tree_nbytes(arrays), n_rows)
         return "full"
 
     def wait_for_snapshot(self) -> None:
@@ -1573,7 +1588,9 @@ def _tree_nbytes(tree) -> int:
 # Pallas scatter program fed by the probe; kinds without a declaration —
 # and the ``backend="xla"`` path — run probe-then-``batched.stacked_update``.
 # The caches are bounded KindCaches: engines evict their kinds' entries on
-# stop/close (``kops.KERNEL_CACHE_SIZE`` gauges them).
+# stop/close (``tracing.KERNEL_CACHE_SIZE`` gauges them). Each program is
+# named by its kind (``fused_update_<Kind>``, ``fused_step_<Kind>``), so a
+# device trace shows ``jit_fused_update_CountMin`` and not a hash.
 # ---------------------------------------------------------------------------
 
 _UPDATE_CACHE = kops.KindCache("update")
@@ -1589,7 +1606,7 @@ def _update_fn(kind, backend: str, sharding, has_sources: bool,
 
         def fused(state, klo, khi, trows, sid_lo, sid_hi, items, vals, msk,
                   *src):
-            kops.TRACE_COUNT[name] += 1     # runs only when jit (re)traces
+            tracing.TRACE_COUNT[name] += 1     # runs only when jit (re)traces
             src_rows = src[0] if has_sources else None
             syn_idx = kops.route_probe(klo, khi, trows, sid_lo, sid_hi,
                                        n_probe=n_probe)   # [-1 => unrouted]
@@ -1601,7 +1618,8 @@ def _update_fn(kind, backend: str, sharding, has_sources: bool,
         kw = dict(donate_argnums=0)
         if sharding is not None:
             kw["out_shardings"] = sharding
-        return jax.jit(fused, **kw)
+        return jax.jit(tracing.named(
+            fused, f"fused_update_{type(kind).__name__}"), **kw)
 
     return _UPDATE_CACHE.get(
         (kind, backend, sharding, has_sources, n_probe), build)
@@ -1609,7 +1627,7 @@ def _update_fn(kind, backend: str, sharding, has_sources: bool,
 
 def _update(kind, backend, sharding, n_probe, state, klo, khi, trows,
             sid_lo, sid_hi, items, vals, msk, src_rows=None):
-    kops.DISPATCH_COUNT[f"update:{type(kind).__name__}"] += 1
+    tracing.DISPATCH_COUNT[f"update:{type(kind).__name__}"] += 1
     fn = _update_fn(kind, backend, sharding, src_rows is not None, n_probe)
     if src_rows is None:
         return fn(state, klo, khi, trows, sid_lo, sid_hi, items, vals, msk)
@@ -1639,7 +1657,8 @@ def _step_fn(kind, sharding, n_probe: int):
         kw = dict(donate_argnums=0)
         if sharding is not None:
             kw["out_shardings"] = sharding
-        return jax.jit(fused, **kw)
+        return jax.jit(tracing.named(
+            fused, f"fused_step_{type(kind).__name__}"), **kw)
 
     return _STEP_CACHE.get((kind, sharding, n_probe), build)
 

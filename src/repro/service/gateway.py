@@ -48,22 +48,26 @@ tick count. This module is that front door:
     before reading the next line gets real backpressure via delayed
     acks (the client's TCP window fills instead of the engine's queue).
 
-Observability: ``kernels.ops.GATEWAY_TICKS`` counts micro-batcher ticks
-per gateway tag and ``GATEWAY_COALESCED`` counts client requests folded
-into coalesced calls — paired with ``DISPATCH_COUNT``, tests assert the
-invariant this module exists for: 64 clients ingesting concurrently
-cost ONE blue-path dispatch per kind per tick, not 64.
+Observability (``repro.tracing``): ``GATEWAY_TICKS`` counts micro-batcher
+ticks per gateway tag and ``GATEWAY_COALESCED`` counts client requests
+folded into coalesced calls — paired with ``DISPATCH_COUNT``, tests
+assert the invariant this module exists for: 64 clients ingesting
+concurrently cost ONE blue-path dispatch per kind per tick, not 64. With
+a profiler session running, spans time each request's wait in the queue
+(``gateway.queue_wait``), the linger before a tick, the tick and its
+coalescing.
 """
 from __future__ import annotations
 
 import asyncio
 import concurrent.futures
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.kernels import ops as kops
+from repro import tracing
 from . import api, pipeline
 from .engine import SDE
 
@@ -120,11 +124,13 @@ class GatewayClient:
 @dataclasses.dataclass
 class _Item:
     """One queued request: submitting client, resolved tenant, the raw
-    request dict, and the future its response resolves."""
+    request dict, the future its response resolves, and when it was
+    submitted (``time.monotonic_ns``)."""
     client: GatewayClient
     tenant: str
     req: Dict[str, Any]
     fut: Any
+    t_submit: int
 
 
 def _future():
@@ -245,7 +251,8 @@ class SynopsisGateway:
                 error=f"tenant name {tenant!r} contains the reserved "
                       f"namespace separator {NS_SEP!r}"))
             return fut
-        self._queue.append(_Item(client, tenant, dict(req), fut))
+        self._queue.append(_Item(client, tenant, dict(req), fut,
+                                 time.monotonic_ns()))
         self._arrival.set()
         return fut
 
@@ -275,7 +282,8 @@ class SynopsisGateway:
             await self._arrival.wait()
             if self.tick_interval > 0:
                 # let a tick's worth of concurrent traffic accumulate
-                await asyncio.sleep(self.tick_interval)
+                with tracing.span("gateway.linger"):
+                    await asyncio.sleep(self.tick_interval)
             self._arrival.clear()
             self.tick()
 
@@ -302,42 +310,49 @@ class SynopsisGateway:
             self._route_continuous()
             self._maybe_reconcile()
             return 0
-        self.ticks += 1
-        self.requests += len(batch)
-        kops.GATEWAY_TICKS[self.tag] += 1
-        runs: List[Tuple[str, List[_Item]]] = []
-        for item in batch:
-            klass = self._class_of(item.req)
-            if (runs and runs[-1][0] == klass
-                    and klass in ("ingest", "query")):
-                runs[-1][1].append(item)
-            else:
-                runs.append((klass, [item]))
-        for klass, items in runs:
-            if self.closed:
-                for it in items:
-                    it.fut.set_result(api.Response(
-                        request_id=str(it.req.get("request_id", "")),
-                        ok=False, error="gateway is shut down"))
-                continue
-            if klass == "ingest":
-                self._do_ingest(items)
-            elif klass == "query":
-                self._do_query(items)
-            else:
-                self._do_one(items[0])
-        if self.wal is not None:
-            # durable-before-ack: one fsync per tick covers every
-            # record this tick appended, before its futures are awaited
-            self.wal.sync()
-        if self.checkpointer is not None and not self.closed:
-            try:
-                self.checkpointer.maybe_snapshot()
-            except Exception as e:  # noqa: BLE001 - serving must survive
-                self.checkpoint_error = repr(e)
-        self._route_continuous()
-        self._maybe_reconcile()
-        return len(batch)
+        if tracing.enabled():
+            now = time.monotonic_ns()
+            for item in batch:
+                tracing.record("gateway.queue_wait", item.t_submit, now,
+                               type=str(item.req.get("type")))
+        with tracing.span("gateway.tick", requests=len(batch)):
+            self.ticks += 1
+            self.requests += len(batch)
+            tracing.GATEWAY_TICKS[self.tag] += 1
+            runs: List[Tuple[str, List[_Item]]] = []
+            for item in batch:
+                klass = self._class_of(item.req)
+                if (runs and runs[-1][0] == klass
+                        and klass in ("ingest", "query")):
+                    runs[-1][1].append(item)
+                else:
+                    runs.append((klass, [item]))
+            for klass, items in runs:
+                if self.closed:
+                    for it in items:
+                        it.fut.set_result(api.Response(
+                            request_id=str(it.req.get("request_id", "")),
+                            ok=False, error="gateway is shut down"))
+                    continue
+                if klass == "ingest":
+                    self._do_ingest(items)
+                elif klass == "query":
+                    self._do_query(items)
+                else:
+                    self._do_one(items[0])
+            if self.wal is not None:
+                # durable-before-ack: one fsync per tick covers every
+                # record this tick appended, before its futures are
+                # awaited
+                self.wal.sync()
+            if self.checkpointer is not None and not self.closed:
+                try:
+                    self.checkpointer.maybe_snapshot()
+                except Exception as e:  # noqa: BLE001 - keep serving
+                    self.checkpoint_error = repr(e)
+            self._route_continuous()
+            self._maybe_reconcile()
+            return len(batch)
 
     def _maybe_reconcile(self) -> None:
         if self.reconciler is None or self.closed:
@@ -360,34 +375,36 @@ class SynopsisGateway:
     # coalesced blue path: one SDE.ingest per run
     # ------------------------------------------------------------------
     def _do_ingest(self, items: List[_Item]) -> None:
-        parts = []                       # (item, sids, vals, mask)
-        for item in items:
-            try:
-                sids = np.asarray(item.req.get("stream_ids", []),
-                                  np.int64).ravel()
-                vals = np.asarray(item.req.get("values", []),
-                                  np.float32).ravel()
-                if len(sids) != len(vals):
-                    raise ValueError(
-                        f"ingest batch mismatch: {len(sids)} stream_ids "
-                        f"vs {len(vals)} values")
-                raw_mask = item.req.get("mask")
-                mask = (np.ones(len(sids), bool) if raw_mask is None
-                        else np.asarray(raw_mask, bool).ravel())
-                if len(mask) != len(sids):
-                    raise ValueError(
-                        f"ingest batch mismatch: {len(sids)} stream_ids "
-                        f"vs {len(mask)} mask entries")
-                parts.append((item, sids, vals, mask))
-            except Exception as e:  # noqa: BLE001 - fails alone
-                item.fut.set_result(api.Response(
-                    request_id=str(item.req.get("request_id", "")),
-                    ok=False, error=repr(e)))
-        if not parts:
-            return
-        sids = np.concatenate([p[1] for p in parts])
-        vals = np.concatenate([p[2] for p in parts])
-        mask = np.concatenate([p[3] for p in parts])
+        with tracing.span("gateway.coalesce", requests=len(items)) as sp:
+            parts = []                   # (item, sids, vals, mask)
+            for item in items:
+                try:
+                    sids = np.asarray(item.req.get("stream_ids", []),
+                                      np.int64).ravel()
+                    vals = np.asarray(item.req.get("values", []),
+                                      np.float32).ravel()
+                    if len(sids) != len(vals):
+                        raise ValueError(
+                            f"ingest batch mismatch: {len(sids)} "
+                            f"stream_ids vs {len(vals)} values")
+                    raw_mask = item.req.get("mask")
+                    mask = (np.ones(len(sids), bool) if raw_mask is None
+                            else np.asarray(raw_mask, bool).ravel())
+                    if len(mask) != len(sids):
+                        raise ValueError(
+                            f"ingest batch mismatch: {len(sids)} "
+                            f"stream_ids vs {len(mask)} mask entries")
+                    parts.append((item, sids, vals, mask))
+                except Exception as e:  # noqa: BLE001 - fails alone
+                    item.fut.set_result(api.Response(
+                        request_id=str(item.req.get("request_id", "")),
+                        ok=False, error=repr(e)))
+            if not parts:
+                return
+            sids = np.concatenate([p[1] for p in parts])
+            vals = np.concatenate([p[2] for p in parts])
+            mask = np.concatenate([p[3] for p in parts])
+            sp.note(events=len(sids))
         try:
             batch_id = self.sde.ingest(sids, vals, mask)
         except Exception as e:  # noqa: BLE001 - service returns errors
@@ -416,7 +433,7 @@ class SynopsisGateway:
                         error=f"ingested but WAL append failed: {e!r}"))
                 return
         self.commit_log.append(("ingest", sids, vals, mask))
-        kops.note_coalesced("ingest", len(parts))
+        tracing.note_coalesced("ingest", len(parts))
         for item, part_sids, _, part_mask in parts:
             item.fut.set_result(api.Response(
                 request_id=str(item.req.get("request_id", "")),
@@ -469,7 +486,7 @@ class SynopsisGateway:
                     request_id=str(item.req.get("request_id", "")),
                     ok=False, error=repr(e)))
             return
-        kops.note_coalesced("query", len(items))
+        tracing.note_coalesced("query", len(items))
         for item, start, many in slices:
             if many is None:             # adhoc: one answer, un-prefixed
                 resp = answers[start]

@@ -29,7 +29,7 @@ This module decouples emission from ingestion:
 The pipeline never re-orders or re-dispatches device work: programs are
 dispatched in ingest order by the engine; this queue only defers the
 host-side materialization. Retirement depth is observable through
-``kernels.ops.PIPELINE_IN_FLIGHT`` / ``PIPELINE_MAX_IN_FLIGHT``.
+``tracing.PIPELINE_IN_FLIGHT`` / ``PIPELINE_MAX_IN_FLIGHT``.
 """
 from __future__ import annotations
 
@@ -37,7 +37,7 @@ import collections
 import dataclasses
 from typing import Any, Callable, List, Optional, Tuple
 
-from repro.kernels import ops as kops
+from repro import tracing
 
 
 class BoundedResponseLog(collections.deque):
@@ -123,7 +123,7 @@ class IngestPipeline:
         self._queue.append(pending)
         while len(self._queue) > self.depth:
             self._retire_oldest()
-        kops.note_in_flight(self.tag, len(self._queue))
+        tracing.note_in_flight(self.tag, len(self._queue))
 
     def flush(self) -> int:
         """Explicit barrier: materialize EVERY pending batch, oldest
@@ -133,7 +133,7 @@ class IngestPipeline:
             self._retire_oldest()
             n += 1
         if n:
-            kops.note_in_flight(self.tag, 0)
+            tracing.note_in_flight(self.tag, 0)
         return n
 
     def _retire_oldest(self) -> None:

@@ -28,7 +28,7 @@ module ACTS on it. A :class:`Reconciler` periodically:
 Hysteresis: a pass applies only when it would improve the max/mean load
 imbalance by at least ``min_gain`` (reconcilers must damp, not flap).
 Skips are cheap — one ``query_many`` dispatch — so tight intervals are
-fine. Probes: ``kernels.ops.RECONCILE_COUNT`` / ``MIGRATED_ROWS`` /
+fine. Probes: ``tracing.RECONCILE_COUNT`` / ``MIGRATED_ROWS`` /
 ``REBALANCE_IMBALANCE``, surfaced by the JSON ``status`` response.
 
 Drive it off the gateway tick (``SynopsisGateway(reconciler=...)``),
@@ -40,7 +40,7 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional, Sequence
 
-from repro.kernels import ops as kops
+from repro import tracing
 from . import balancer
 from .engine import SDE, Federation
 from .routing import next_pow2
@@ -166,11 +166,11 @@ class Reconciler:
         federation, under every member site too, so each site's JSON
         ``status`` (which reads the probes by its own site tag) shows
         the control loop's activity."""
-        kops.note_reconcile(self.tag, imbalance)
+        tracing.note_reconcile(self.tag, imbalance)
         if self.federated:
             for site in self.target.sites:
                 if site != self.tag:
-                    kops.note_reconcile(site, imbalance)
+                    tracing.note_reconcile(site, imbalance)
 
     def _skip(self, reason: str) -> dict:
         # same schema as the hysteresis/applied paths — consumers index

@@ -39,7 +39,7 @@ from typing import Any, Dict, Iterator, Optional
 
 import numpy as np
 
-from repro.kernels import ops as kops
+from repro import tracing
 from .api import MUTATING_REQUESTS  # noqa: F401 — the replay set lives
 #     with the request schemata now; re-exported here for compatibility
 from .engine import SDE
@@ -80,12 +80,13 @@ class WriteAheadLog:
         before the ack leaves — so the WAL never holds a record for an
         ingest that failed live, and batch ids in the log are exactly
         the acked ones."""
-        return self._append(dict(
-            kind="ingest", batch=int(batch),
-            sids=np.asarray(stream_ids, np.int64).ravel().tolist(),
-            vals=np.asarray(values, np.float32).ravel().tolist(),
-            mask=(None if mask is None
-                  else np.asarray(mask, bool).ravel().tolist())))
+        with tracing.span("wal.encode", events=len(stream_ids)):
+            return self._append(dict(
+                kind="ingest", batch=int(batch),
+                sids=np.asarray(stream_ids, np.int64).ravel().tolist(),
+                vals=np.asarray(values, np.float32).ravel().tolist(),
+                mask=(None if mask is None
+                      else np.asarray(mask, bool).ravel().tolist())))
 
     def append_ingest_multidim(self, batch: int,
                                req: Dict[str, Any]) -> int:
@@ -107,7 +108,7 @@ class WriteAheadLog:
         rec["seq"] = self.seq
         self._fh.write(json.dumps(rec) + "\n")
         self._dirty = True
-        kops.note_wal_append(self.tag)
+        tracing.note_wal_append(self.tag)
         return self.seq
 
     def sync(self) -> None:
@@ -115,8 +116,9 @@ class WriteAheadLog:
         serving loop's durable-before-ack point."""
         if not self._dirty:
             return
-        self._fh.flush()
-        os.fsync(self._fh.fileno())
+        with tracing.span("wal.sync"):
+            self._fh.flush()
+            os.fsync(self._fh.fileno())
         self._dirty = False
 
     def truncate_through(self, seq: int) -> None:
@@ -275,32 +277,33 @@ class Checkpointer:
         return self.snapshot()
 
     def snapshot(self) -> str:
-        failures = self.sde.ckpt_failures
-        seq_now = self.sde.wal_seq
-        mode = self.sde.snapshot(
-            self.directory, self.next_step,
-            incremental=self.incremental, keep=self.keep,
-            async_=self.async_, rebase_every=self.rebase_every)
-        # Truncate only through a snapshot KNOWN durable. Sync saves
-        # land before SDE.snapshot returns (a failure raises above);
-        # async saves lag one snapshot — SDE.snapshot joined the
-        # previous background write and bumped ckpt_failures if it
-        # never landed, so an unchanged counter certifies it.
-        durable_seq = None
-        if not self.async_:
-            durable_seq = seq_now
-        elif self._last_snap_seq and self.sde.ckpt_failures == failures:
-            durable_seq = self._last_snap_seq
-        if self.wal is not None and durable_seq:
-            try:
-                self.wal.truncate_through(durable_seq)
-            except OSError:
-                pass                     # rotation is best-effort only
-        self._last_snap_seq = seq_now
-        self.next_step += 1
-        self._last_batches = self.sde.batches_ingested
-        self.snapshots += 1
-        return mode
+        with tracing.span("checkpoint.snapshot"):
+            failures = self.sde.ckpt_failures
+            seq_now = self.sde.wal_seq
+            mode = self.sde.snapshot(
+                self.directory, self.next_step,
+                incremental=self.incremental, keep=self.keep,
+                async_=self.async_, rebase_every=self.rebase_every)
+            # Truncate only through a snapshot KNOWN durable. Sync saves
+            # land before SDE.snapshot returns (a failure raises above);
+            # async saves lag one snapshot — SDE.snapshot joined the
+            # previous background write and bumped ckpt_failures if it
+            # never landed, so an unchanged counter certifies it.
+            durable_seq = None
+            if not self.async_:
+                durable_seq = seq_now
+            elif self._last_snap_seq and self.sde.ckpt_failures == failures:
+                durable_seq = self._last_snap_seq
+            if self.wal is not None and durable_seq:
+                try:
+                    self.wal.truncate_through(durable_seq)
+                except OSError:
+                    pass                     # rotation is best-effort only
+            self._last_snap_seq = seq_now
+            self.next_step += 1
+            self._last_batches = self.sde.batches_ingested
+            self.snapshots += 1
+            return mode
 
 
 def recover(checkpoint_dir: Optional[str], wal_path: Optional[str], *,

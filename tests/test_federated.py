@@ -27,9 +27,8 @@ import numpy as np
 import jax
 import pytest
 
-from repro import core
+from repro import core, tracing
 from repro.core import federated
-from repro.kernels import ops as kops
 from repro.service import Federation
 
 N_SITES = 4
@@ -396,8 +395,8 @@ def test_mesh_collective_one_dispatch_and_metrics():
         for f in (fed, oracle):
             f.sdes[name].ingest(sids.copy(), np.ones(256, np.float32))
     want = oracle.query_federated("cm", {"items": [1, 2, 3]}, "eu")
-    kops.DISPATCH_COUNT.clear()
-    kops.TRACE_COUNT.clear()
+    tracing.DISPATCH_COUNT.clear()
+    tracing.TRACE_COUNT.clear()
     for _ in range(3):
         r = fed.handle({"type": "federated_query", "request_id": "q",
                         "synopsis_id": "cm", "query": {"items": [1, 2, 3]},
@@ -406,9 +405,9 @@ def test_mesh_collective_one_dispatch_and_metrics():
         np.testing.assert_array_equal(np.asarray(r.value),
                                       np.asarray(want))
     # merge + estimate fused into ONE collective program per query...
-    assert kops.DISPATCH_COUNT["CountMin"] == 3
+    assert tracing.DISPATCH_COUNT["CountMin"] == 3
     # ... and repeated queries reuse ONE compiled program
-    assert kops.TRACE_COUNT["CountMin"] == 1
+    assert tracing.TRACE_COUNT["CountMin"] == 1
     assert r.params["path"] == "collective"
     # CM is a linear sketch: the psum combines in-network, so the
     # collective ships ONE state regardless of the site count
@@ -427,9 +426,8 @@ _MESH_MATRIX_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import numpy as np, jax
-    from repro import core
+    from repro import core, tracing
     from repro.core import federated
-    from repro.kernels import ops as kops
     from repro.launch.mesh import make_federation_mesh
     from repro.service import Federation
 
@@ -463,8 +461,8 @@ _MESH_MATRIX_SCRIPT = textwrap.dedent("""
                                         vals.copy())
         q = QUERY.get(kind_name, {})
         want = oracle.query_federated("g", q, sites[0])
-        kops.DISPATCH_COUNT.clear()
-        kops.TRACE_COUNT.clear()
+        tracing.DISPATCH_COUNT.clear()
+        tracing.TRACE_COUNT.clear()
         pname = type(core.make_kind(kind_name,
                                     **PARAMS[kind_name])).__name__
         for rep in range(2):
@@ -478,10 +476,10 @@ _MESH_MATRIX_SCRIPT = textwrap.dedent("""
                                               np.asarray(b),
                                               err_msg=kind_name)
         assert r.params["path"] == "collective", kind_name
-        assert kops.DISPATCH_COUNT[pname] == 2, (kind_name,
-                                                 kops.DISPATCH_COUNT)
-        assert kops.TRACE_COUNT[pname] == 1, (kind_name,
-                                              kops.TRACE_COUNT)
+        assert tracing.DISPATCH_COUNT[pname] == 2, (kind_name,
+                                                 tracing.DISPATCH_COUNT)
+        assert tracing.TRACE_COUNT[pname] == 1, (kind_name,
+                                              tracing.TRACE_COUNT)
         assert r.params["collective_operand_bytes"] \\
             <= r.params["host_merge_bytes"], kind_name
         print(kind_name, "OK")

@@ -24,7 +24,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.kernels import ops as kops
+from repro import tracing
 from repro.launch import sde_server
 from repro.service import SDE, SynopsisGateway, replay_log
 
@@ -156,15 +156,15 @@ def test_64_clients_one_blue_dispatch_per_tick():
     clients = [gw.connect(f"c{i}") for i in range(64)]
     gw.submit_nowait(clients[0], _build())
     gw.tick()
-    d0 = kops.DISPATCH_COUNT.get("update:CountMin", 0)
-    c0 = kops.GATEWAY_COALESCED.get("ingest", 0)
+    d0 = tracing.DISPATCH_COUNT.get("update:CountMin", 0)
+    c0 = tracing.GATEWAY_COALESCED.get("ingest", 0)
     rng = np.random.RandomState(0)
     futs = [gw.submit_nowait(c, _ingest(f"i{i}", rng.randint(0, 100, 16)))
             for i, c in enumerate(clients)]
     n = gw.tick()
     assert n == 64
-    assert kops.DISPATCH_COUNT["update:CountMin"] - d0 == 1
-    assert kops.GATEWAY_COALESCED["ingest"] - c0 == 64
+    assert tracing.DISPATCH_COUNT["update:CountMin"] - d0 == 1
+    assert tracing.GATEWAY_COALESCED["ingest"] - c0 == 64
     acks = [f.result() for f in futs]
     assert all(a.ok for a in acks)
     # every client was folded into the SAME engine batch
@@ -183,7 +183,7 @@ def test_query_run_coalesces_to_one_red_dispatch():
     sids = np.array([1] * 3 + [2] * 5)
     gw.submit_nowait(acme, _ingest("i", sids))
     gw.tick()
-    d0 = kops.DISPATCH_COUNT.get("CountMin", 0)
+    d0 = tracing.DISPATCH_COUNT.get("CountMin", 0)
     q0 = gw.submit_nowait(acme, {"type": "adhoc", "request_id": "qa",
                                  "synopsis_id": "cm",
                                  "query": {"items": [1]}})
@@ -197,7 +197,7 @@ def test_query_run_coalesces_to_one_red_dispatch():
                                      {"synopsis_id": "nope"}]})
     gw.tick()
     # one stacked-estimate dispatch answered all three requests
-    assert kops.DISPATCH_COUNT["CountMin"] - d0 == 1
+    assert tracing.DISPATCH_COUNT["CountMin"] - d0 == 1
     assert float(np.ravel(q0.result().value)[0]) == 3.0
     assert float(np.ravel(q1.result().value)[0]) == 5.0
     many = q2.result()
@@ -221,8 +221,8 @@ def test_concurrent_clients_match_serialized_oracle():
     async def drive():
         gw = SynopsisGateway(SDE(), tick_interval=0.001)
         await gw.start()
-        d0 = kops.DISPATCH_COUNT.get("update:CountMin", 0)
-        c0 = kops.GATEWAY_COALESCED.get("ingest", 0)
+        d0 = tracing.DISPATCH_COUNT.get("update:CountMin", 0)
+        c0 = tracing.GATEWAY_COALESCED.get("ingest", 0)
 
         async def client_traffic(j):
             tenant = f"t{j % 4}"
@@ -251,8 +251,8 @@ def test_concurrent_clients_match_serialized_oracle():
     n_ingest_reqs = 8 * 6
     # every coalesced call was ONE dispatch; concurrency actually
     # amortized (strictly fewer engine calls than client requests)
-    assert kops.DISPATCH_COUNT["update:CountMin"] - d0 == n_ingest_calls
-    assert kops.GATEWAY_COALESCED["ingest"] - c0 == n_ingest_reqs
+    assert tracing.DISPATCH_COUNT["update:CountMin"] - d0 == n_ingest_calls
+    assert tracing.GATEWAY_COALESCED["ingest"] - c0 == n_ingest_reqs
     assert n_ingest_calls < n_ingest_reqs
     gw.sde.flush()
     _assert_states_equal(gw.sde, replay_log(gw.commit_log))

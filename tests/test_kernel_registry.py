@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import core
+from repro import core, tracing
 from repro.core import batched
 from repro.kernels import ops
 from repro.service import SDE
@@ -193,14 +193,14 @@ def test_fused_pallas_update_one_trace_one_dispatch_per_batch():
                                "weighted": False},
                     "per_stream_of_source": True, "n_streams": 16})
     assert r.ok, r.error
-    d0 = ops.DISPATCH_COUNT["update:CountMin"]
-    t0 = ops.TRACE_COUNT["update:CountMin"]
+    d0 = tracing.DISPATCH_COUNT["update:CountMin"]
+    t0 = tracing.TRACE_COUNT["update:CountMin"]
     rng = np.random.RandomState(0)
     for _ in range(3):
         eng.ingest(rng.randint(0, 16, 128).astype(np.uint32),
                    np.ones(128, np.float32))
-    assert ops.DISPATCH_COUNT["update:CountMin"] - d0 == 3
-    assert ops.TRACE_COUNT["update:CountMin"] - t0 == 1
+    assert tracing.DISPATCH_COUNT["update:CountMin"] - d0 == 3
+    assert tracing.TRACE_COUNT["update:CountMin"] - t0 == 1
     eng.close()
 
 
@@ -208,7 +208,7 @@ def test_fused_pallas_update_one_trace_one_dispatch_per_batch():
 # bounded caches: stop/close release the kind's compiled programs
 # ---------------------------------------------------------------------------
 def test_update_cache_entries_released_on_stop():
-    g0 = ops.KERNEL_CACHE_SIZE["update"]
+    g0 = tracing.KERNEL_CACHE_SIZE["update"]
     eng = SDE(backend="xla")
     r = eng.handle({"type": "build", "request_id": "b", "synopsis_id": "c",
                     "kind": "countmin",
@@ -217,15 +217,15 @@ def test_update_cache_entries_released_on_stop():
                     "per_stream_of_source": True, "n_streams": 8})
     assert r.ok, r.error
     eng.ingest(np.arange(8, dtype=np.uint32), np.ones(8, np.float32))
-    assert ops.KERNEL_CACHE_SIZE["update"] > g0
+    assert tracing.KERNEL_CACHE_SIZE["update"] > g0
     r = eng.handle({"type": "stop", "request_id": "s", "synopsis_id": "c"})
     assert r.ok, r.error
-    assert ops.KERNEL_CACHE_SIZE["update"] == g0
+    assert tracing.KERNEL_CACHE_SIZE["update"] == g0
     assert not eng.stacks
 
 
 def test_close_releases_every_kind_cache_entry():
-    g0 = {c: ops.KERNEL_CACHE_SIZE[c] for c in ("update", "step")}
+    g0 = {c: tracing.KERNEL_CACHE_SIZE[c] for c in ("update", "step")}
     eng = SDE(backend="pallas")
     for i, (kname, params) in enumerate([
             ("hyperloglog", {"rse": 0.0987}),
@@ -237,11 +237,11 @@ def test_close_releases_every_kind_cache_entry():
                         "n_streams": 8})
         assert r.ok, r.error
     eng.ingest(np.arange(8, dtype=np.uint32), np.ones(8, np.float32))
-    assert ops.KERNEL_CACHE_SIZE["update"] > g0["update"]
-    assert ops.KERNEL_CACHE_SIZE["step"] > g0["step"]
+    assert tracing.KERNEL_CACHE_SIZE["update"] > g0["update"]
+    assert tracing.KERNEL_CACHE_SIZE["step"] > g0["step"]
     eng.close()
     for c in ("update", "step"):
-        assert ops.KERNEL_CACHE_SIZE[c] == g0[c]
+        assert tracing.KERNEL_CACHE_SIZE[c] == g0[c]
     assert not eng.stacks and not eng.entries
 
 

@@ -10,7 +10,7 @@ Covers the tentpole contract:
   * flush — the explicit barrier drains ALL pending batches; monotonic
     batch-counter request ids are preserved under overlap;
   * satellites — bounded ``continuous_out`` with a dropped-count stat,
-    in-flight depth probes in ``kernels.ops``, ingest length-mismatch
+    in-flight depth probes in ``repro.tracing``, ingest length-mismatch
     guard, JSON ``ingest``/``flush`` requests with batch-counter acks,
     and the launch-layer JSON-lines server.
 """
@@ -22,8 +22,7 @@ import numpy as np
 import jax
 import pytest
 
-from repro import core
-from repro.kernels import ops as kops
+from repro import core, tracing
 from repro.service import SDE
 
 _PARAMS = {
@@ -180,18 +179,18 @@ def test_monotonic_batch_ids_under_overlap():
 
 def test_in_flight_depth_probes():
     tag = "probe-site"
-    kops.PIPELINE_IN_FLIGHT.pop(tag, None)
-    kops.PIPELINE_MAX_IN_FLIGHT.pop(tag, None)
+    tracing.PIPELINE_IN_FLIGHT.pop(tag, None)
+    tracing.PIPELINE_MAX_IN_FLIGHT.pop(tag, None)
     eng = SDE(site=tag, pipelined=True, pipeline_depth=2)
     _build_continuous(eng, "hyperloglog")
     for b in _batches(n_batches=4):
         eng.ingest(*b)
     # the bounded queue really double-buffers: depth reached, never beyond
-    assert kops.PIPELINE_MAX_IN_FLIGHT[tag] == 2
-    assert kops.PIPELINE_IN_FLIGHT[tag] == 2
+    assert tracing.PIPELINE_MAX_IN_FLIGHT[tag] == 2
+    assert tracing.PIPELINE_IN_FLIGHT[tag] == 2
     eng.flush()
-    assert kops.PIPELINE_IN_FLIGHT[tag] == 0
-    assert kops.PIPELINE_MAX_IN_FLIGHT[tag] == 2
+    assert tracing.PIPELINE_IN_FLIGHT[tag] == 0
+    assert tracing.PIPELINE_MAX_IN_FLIGHT[tag] == 2
 
 
 def test_bad_pipeline_depth_rejected():

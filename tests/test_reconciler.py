@@ -27,7 +27,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.kernels import ops as kops
+from repro import tracing
 from repro.launch import sde_server
 from repro.service import (SDE, Federation, Placement, Reconciler,
                            SynopsisGateway, worst_fit_decreasing)
@@ -190,7 +190,7 @@ def test_reconcile_single_engine_rebalances():
     streams = list(range(32))
     eng = _mk_engine(streams)
     rec = Reconciler(eng, "rhll", "rcm", n_workers=4, min_gain=0.0)
-    count0 = kops.RECONCILE_COUNT[eng.site]
+    count0 = tracing.RECONCILE_COUNT[eng.site]
 
     eng.ingest(*_skewed(streams, hot=[0, 1]))
     rep = rec.step()
@@ -207,7 +207,7 @@ def test_reconcile_single_engine_rebalances():
     # probes reached the JSON status response
     st = eng.handle({"type": "status", "request_id": "s"})
     assert st.params["reconcile_count"] \
-        == kops.RECONCILE_COUNT[eng.site] > count0
+        == tracing.RECONCILE_COUNT[eng.site] > count0
     assert st.params["migrated_rows"] >= rep["migrated_rows"]
     assert st.params["rebalance_imbalance"] \
         == pytest.approx(rep["imbalance_after"])
@@ -340,7 +340,7 @@ def test_reconcile_federated_ships_synopses():
     moved = [s for s in streams if f"pt/{s}" in fed.sdes["us"].entries]
     stayed = [s for s in streams if f"pt/{s}" in fed.sdes["eu"].entries]
     assert sorted(moved + stayed) == streams and moved
-    assert kops.RECONCILE_COUNT["federation"] > 0
+    assert tracing.RECONCILE_COUNT["federation"] > 0
     # federated passes are ALSO tagged per member site, so each site's
     # JSON status (keyed by its own site tag) shows the loop's activity
     for site in ("eu", "us"):

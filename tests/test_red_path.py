@@ -21,9 +21,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import core
+from repro import core, tracing
 from repro.core import batched
-from repro.kernels import ops as kops
 from repro.service import SDE, Federation, api
 from repro.service import engine as engine_mod
 
@@ -147,17 +146,17 @@ def test_query_many_single_dispatch_per_kind():
                            query={"items": [int(s)]})
             for s in range(20)]
     reqs.append(api.AdHocQuery(request_id="qh", synopsis_id="hll"))
-    kops.DISPATCH_COUNT.clear()
-    kops.TRACE_COUNT.clear()
+    tracing.DISPATCH_COUNT.clear()
+    tracing.TRACE_COUNT.clear()
     n_batches = 3
     for _ in range(n_batches):
         rs = eng.query_many(reqs)
     # N queries against a kind = ONE dispatch for that kind per batch
-    assert kops.DISPATCH_COUNT["CountMin"] == n_batches
-    assert kops.DISPATCH_COUNT["HyperLogLog"] == n_batches
+    assert tracing.DISPATCH_COUNT["CountMin"] == n_batches
+    assert tracing.DISPATCH_COUNT["HyperLogLog"] == n_batches
     # ... and every same-shape batch reuses ONE compiled program
-    assert kops.TRACE_COUNT["CountMin"] == 1
-    assert kops.TRACE_COUNT["HyperLogLog"] == 1
+    assert tracing.TRACE_COUNT["CountMin"] == 1
+    assert tracing.TRACE_COUNT["HyperLogLog"] == 1
     # correctness: unweighted per-stream CM counts are exact
     for s in range(20):
         assert float(rs[s].value[0]) == float((sids == s).sum()), s
@@ -241,10 +240,10 @@ def test_federated_query_single_fused_dispatch():
                           np.ones(2000, np.float32))
     fed.sdes["us"].ingest(np.arange(1000, 3000, dtype=np.uint32),
                           np.ones(2000, np.float32))
-    kops.DISPATCH_COUNT.clear()
+    tracing.DISPATCH_COUNT.clear()
     est = float(fed.query_federated("h", {}, "eu"))
     # merge-over-sites + estimate fused into one program
-    assert kops.DISPATCH_COUNT["HyperLogLog"] == 1
+    assert tracing.DISPATCH_COUNT["HyperLogLog"] == 1
     assert abs(est - 3000) / 3000 < 0.1
 
 
@@ -273,13 +272,13 @@ def test_continuous_emission_batched_no_stacked_row(monkeypatch):
     orig_plan = engine_mod._plan_queries
     monkeypatch.setattr(engine_mod, "_plan_queries",
                         lambda *a: plans.append(1) or orig_plan(*a))
-    kops.DISPATCH_COUNT.clear()
+    tracing.DISPATCH_COUNT.clear()
     sids = np.arange(10, dtype=np.uint32).repeat(20)
     eng.ingest(sids, np.ones(len(sids), np.float32))
     # 10 per-stream CMs + 1 HLL, all continuous
     assert len(eng.continuous_out) == 11
-    assert kops.DISPATCH_COUNT["CountMin"] == 1
-    assert kops.DISPATCH_COUNT["HyperLogLog"] == 1
+    assert tracing.DISPATCH_COUNT["CountMin"] == 1
+    assert tracing.DISPATCH_COUNT["HyperLogLog"] == 1
     # the grouping + arg planning is cached: further ingests re-dispatch
     # without re-planning on the host
     eng.ingest(sids, np.ones(len(sids), np.float32))
@@ -299,9 +298,9 @@ _MESH_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax
     from jax.sharding import NamedSharding
+    from repro import tracing
     from repro.service import SDE, api
     from repro.launch.mesh import make_mesh
-    from repro.kernels import ops as kops
 
     mesh = make_mesh((4, 2), ("data", "model"))
     eng = SDE(mesh=mesh)
@@ -315,13 +314,13 @@ _MESH_SCRIPT = textwrap.dedent("""
                 "continuous": True})
     rng = np.random.RandomState(0)
     sids = rng.randint(0, 12, 512).astype(np.uint32)
-    kops.DISPATCH_COUNT.clear()
+    tracing.DISPATCH_COUNT.clear()
     n_batches = 3
     for _ in range(n_batches):
         eng.ingest(sids, np.ones(512, np.float32))
     # all continuous queries of a kind = ONE estimate dispatch per ingest
-    assert kops.DISPATCH_COUNT["CountMin"] == n_batches
-    assert kops.DISPATCH_COUNT["HyperLogLog"] == n_batches
+    assert tracing.DISPATCH_COUNT["CountMin"] == n_batches
+    assert tracing.DISPATCH_COUNT["HyperLogLog"] == n_batches
     assert len(eng.continuous_out) == n_batches * 13
     # state stays row-sharded over the synopsis axis after queries
     for stack in eng.stacks.values():
@@ -428,12 +427,12 @@ def test_balancer_uses_batched_query_path():
                            "weighted": False}})
     sids = np.arange(32, dtype=np.uint32).repeat(8)
     eng.ingest(sids, np.ones(len(sids), np.float32))
-    kops.DISPATCH_COUNT.clear()
+    tracing.DISPATCH_COUNT.clear()
     n_active, loads = estimate_workload(eng, "card", "freq",
                                         list(range(32)))
     # the 32 per-stream loads are ONE CM dispatch, not 32
-    assert kops.DISPATCH_COUNT["CountMin"] == 1
-    assert kops.DISPATCH_COUNT["HyperLogLog"] == 1
+    assert tracing.DISPATCH_COUNT["CountMin"] == 1
+    assert tracing.DISPATCH_COUNT["HyperLogLog"] == 1
     assert abs(n_active - 32) < 6
     np.testing.assert_allclose(loads, 8.0)
     with pytest.raises(KeyError):

@@ -17,8 +17,8 @@ The acceptance matrix of the tentpole:
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.core import MultidimSpec
-from repro.kernels import ops as kops
 from repro.service import SDE
 
 _DIMS = {"region": ["EU", "US", "APAC", "LATAM"],
@@ -186,16 +186,16 @@ def test_subpop_single_fused_dispatch():
                            ({"region": ["EU", "US", "APAC"]}, 3),
                            ({"region": ["EU", "US"],
                              "platform": ["web", "mobile"]}, 4)]:
-        d0 = int(kops.DISPATCH_COUNT["CountMin"])
-        c0 = int(kops.SUBPOP_COVER_KEYS[sde.site])
+        d0 = int(tracing.DISPATCH_COUNT["CountMin"])
+        c0 = int(tracing.SUBPOP_COVER_KEYS[sde.site])
         r = sde.handle({"type": "subpop_query", "request_id": "q",
                         "synopsis_id": "md", "where": where,
                         "query": {"items": [7]}})
         assert r.ok, r.error
         assert r.params["cover_keys"] == n_cover
-        assert int(kops.DISPATCH_COUNT["CountMin"]) - d0 == 1, \
+        assert int(tracing.DISPATCH_COUNT["CountMin"]) - d0 == 1, \
             "a covering set must merge+estimate in ONE fused dispatch"
-        assert int(kops.SUBPOP_COVER_KEYS[sde.site]) - c0 == n_cover
+        assert int(tracing.SUBPOP_COVER_KEYS[sde.site]) - c0 == n_cover
     sde.close()
 
 
@@ -417,7 +417,7 @@ def test_outlier_workflow_zero_additional_builds():
     sde.flush()
     n_entries = len(sde.entries)
     caps = {k: s.capacity for k, s in sde.stacks.items()}
-    e0 = int(kops.OUTLIER_EMITS[sde.site])
+    e0 = int(tracing.OUTLIER_EMITS[sde.site])
     r = sde.handle({"type": "track_outliers", "request_id": "t",
                     "workflow_id": "w", "synopsis_id": "md",
                     "level": ["region"], "query": {"items": [1]},
@@ -433,7 +433,7 @@ def test_outlier_workflow_zero_additional_builds():
     # stack grew, yet emissions flowed
     assert len(sde.entries) == n_entries
     assert {k: s.capacity for k, s in sde.stacks.items()} == caps
-    assert int(kops.OUTLIER_EMITS[sde.site]) > e0
+    assert int(tracing.OUTLIER_EMITS[sde.site]) > e0
     assert any(resp.synopsis_id == "w"
                for resp in sde.continuous_out.drain())
     # untrack silences the stream
