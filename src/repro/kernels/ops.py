@@ -104,7 +104,7 @@ def route_probe(keys_lo: jax.Array, keys_hi: jax.Array, rows: jax.Array,
     """Rows for a batch of stream ids via linear probing: ``-1`` for
     unrouted ids. Keys are stored as uint32 (lo, hi) halves so the probe
     needs no 64-bit lanes; ``n_probe`` is the static trip count (the
-    table's longest insert displacement, pow2-rounded by the engine so
+    table's longest displacement + 1, pow2-rounded by the engine so
     retraces stay bounded). The probe is a ``fori_loop`` gather chain —
     plain jnp, fusable into the caller's single blue-path dispatch. The
     slot hash must stay in lockstep with ``service.routing.slot_hash``.

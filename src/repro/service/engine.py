@@ -167,7 +167,7 @@ class _KindStack:
     @property
     def n_probe(self) -> int:
         """Static probe bound for the fused programs: the table's longest
-        insert displacement, pow2-rounded so jit retraces are bounded by
+        displacement + 1, pow2-rounded so jit retraces are bounded by
         log(PROBE_CAP) distinct values, not one per table state."""
         return _next_pow2(self.table.max_probe)
 
@@ -881,7 +881,8 @@ class SDE:
                 vals = jnp.asarray(vals_np)
                 msk = jnp.asarray(mask)
             for kind, stack in self.stacks.items():
-                with tracing.span("ingest.update", kind=type(kind).__name__):
+                with tracing.span("ingest.update", kind=type(kind).__name__,
+                                  n_probe=stack.n_probe):
                     if stack.is_timeseries:
                         self._ingest_timeseries(stack, sid_lo, sid_hi, vals,
                                                 msk)

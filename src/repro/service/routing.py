@@ -3,8 +3,8 @@
 Replaces the fixed ``route[_MAX_STREAMS]`` dense table that silently
 dropped every tuple with a stream id >= 2**16 (and rejected such builds).
 A :class:`RouteTable` is an open-addressing hash table with linear
-probing: pow2-sized ``keys``/``rows`` arrays, tombstone-free inserts on
-build, full re-insert compaction on stop, and grow-and-rehash past
+probing: pow2-sized ``keys``/``rows`` arrays, tombstone-free inserts,
+compaction by a full rebuild on stop, and grow-and-rehash past
 ~``_MAX_LOAD`` load factor. Stream ids are arbitrary ints in
 ``[0, 2**63)`` — nothing is ever clamped, rejected or dropped for being
 "too big".
@@ -21,12 +21,24 @@ Split of responsibilities:
     probe needs no 64-bit lanes (``jax_enable_x64`` stays off); it is
     replicated over multi-device meshes exactly like the old dense route.
 
+**Layout (Robin Hood).** Along every cluster keys sit in order of home
+slot (``slot_hash``), ties broken by key: each key's displacement from
+its home is at most one more than its predecessor's. The layout thus
+depends only on the set of keys, never on the order they came in, and it
+spreads displacement evenly: the ids 0..4,999 in 8,192 slots probe at
+most 8 slots, where first-come placement of the same ids probes 30. Lookup,
+on the host or the device, is plain linear probing to the key or to an
+empty slot, which is right for any layout with no empty slot inside a
+key's chain; tables written with first-come placement (older
+snapshots) are read as they are.
+
 The probe loop's trip count must be static under jit, so the table
-tracks the longest insertion displacement (``max_probe``) and grows
-whenever an insert would displace past :data:`PROBE_CAP` — this bounds
-the fused gather chain (and jit retraces: the engine rounds ``max_probe``
-up to a power of two) independent of table occupancy. In practice tables
-settle around 0.25-0.5 load with probe chains <= 32.
+keeps ``max_probe``, its longest displacement + 1, and grows whenever
+that would pass :data:`PROBE_CAP` — this bounds the fused gather chain
+(and jit retraces: the engine rounds ``max_probe`` up to a power of two)
+independent of table occupancy. 2^20 random ids fit 2^21 slots with
+probes of at most 11; first-come placement passed the cap there and
+doubled the table.
 """
 from __future__ import annotations
 
@@ -85,14 +97,15 @@ def slot_hash(lo: np.ndarray, hi: np.ndarray, size: int) -> np.ndarray:
 
 
 class RouteTable:
-    """Host-side open-addressing stream->row map (linear probing)."""
+    """Host-side open-addressing stream->row map: linear probing over a
+    Robin Hood layout (see the module docstring)."""
 
     def __init__(self, size: int = _MIN_SIZE):
         size = max(_MIN_SIZE, next_pow2(size))
         self.keys = np.full((size,), EMPTY, np.int64)
         self.rows = np.full((size,), -1, np.int32)
         self.count = 0
-        self.max_probe = 1      # longest insert displacement + 1
+        self.max_probe = 1      # longest displacement + 1
         self.version = 0        # bumped on any mutation (device cache key)
 
     # -- read ----------------------------------------------------------
@@ -119,26 +132,14 @@ class RouteTable:
         return -1
 
     def lookup_many(self, sids: np.ndarray) -> np.ndarray:
-        """Vectorized ``lookup``: int32 rows, -1 where a key is absent —
-        the same probe rounds as ``_contains_many``, returning the row
-        instead of a membership bit. The dirty-tracking resolver runs a
-        whole ingest window of stream ids through this in a handful of
-        numpy passes."""
+        """Vectorized ``lookup``: int32 rows, -1 where a key is absent.
+        The dirty-tracking resolver runs a whole ingest window of stream
+        ids through this in a handful of numpy passes."""
         sids = np.asarray(sids, np.int64).ravel()
         out = np.full(sids.shape, -1, np.int32)
-        if sids.size == 0 or self.count == 0:
-            return out
-        slot = slot_hash(*split64(sids), self.size)
-        mask = self.size - 1
-        active = np.ones(sids.shape, bool)
-        for _ in range(self.max_probe):
-            k = self.keys[slot]
-            hit = active & (k == sids)
-            out[hit] = self.rows[slot[hit]]
-            active &= ~hit & (k != EMPTY)
-            if not active.any():
-                break
-            slot = (slot + 1) & mask
+        slot = self._slots(sids)
+        hit = slot >= 0
+        out[hit] = self.rows[slot[hit]]
         return out
 
     def items(self) -> Tuple[np.ndarray, np.ndarray]:
@@ -151,9 +152,11 @@ class RouteTable:
         self.insert_many([sid], [row])
 
     def insert_many(self, sids: np.ndarray, rows: np.ndarray) -> None:
-        """Bulk insert (vectorized rounds of probing — a 1M-stream build
-        is a handful of numpy passes, not 1M Python probes). Re-inserting
-        an existing key updates its row."""
+        """Insert or re-route keys; the layout stays the Robin Hood one.
+        Re-inserting an existing key updates its row in place. New keys
+        re-lay out only the clusters they land in, unless the table grows
+        (then every key is placed afresh): a 1M-stream build is a handful
+        of numpy passes, not 1M Python probes."""
         try:
             sids = np.asarray(sids, np.int64)
         except OverflowError as e:
@@ -175,11 +178,23 @@ class RouteTable:
             raise ValueError(
                 f"stream id {int(bad)} outside [0, 2**63) — ids must be "
                 "non-negative 63-bit ints")
-        # reserve for genuinely NEW keys only: re-inserts (row updates)
-        # must not count toward load or trigger a pointless grow
-        fresh = int(np.count_nonzero(~self._contains_many(sids)))
-        self._reserve(self.count + fresh)
-        self._insert_rounds(sids, rows)
+        slot = self._slots(sids)
+        old = slot >= 0
+        self.rows[slot[old]] = rows[old]
+        # only genuinely NEW keys count toward load and may grow the table
+        sids, rows = sids[~old], rows[~old]
+        if sids.size:
+            size = self.size
+            while self.count + sids.size > _MAX_LOAD * size:
+                size *= 2
+            if size == self.size:
+                self._merge_in(sids, rows)
+                if self.max_probe > PROBE_CAP:
+                    self._rebuild(*self.items(), 2 * self.size)
+            else:
+                keys, old_rows = self.items()
+                self._rebuild(np.concatenate([keys, sids]),
+                              np.concatenate([old_rows, rows]), size)
         self.version += 1
 
     def remap_rows(self, old_rows: np.ndarray, new_rows: np.ndarray) -> None:
@@ -205,9 +220,9 @@ class RouteTable:
         self.version += 1
 
     def remove_rows(self, dead_rows: np.ndarray) -> None:
-        """Drop every key routed to ``dead_rows`` and compact by full
-        re-insert (tombstone-free: stop is the rare path, and rebuilding
-        keeps probe chains at their insert-time bound)."""
+        """Drop every key routed to ``dead_rows`` and compact by a full
+        rebuild (tombstone-free: stop is the rare path, and the rebuild
+        lays the remaining keys out as if they alone had been inserted)."""
         dead = np.asarray(dead_rows, np.int32)
         keys, rows = self.items()
         keep = ~np.isin(rows, dead)
@@ -219,80 +234,117 @@ class RouteTable:
         self.version += 1
 
     # -- internals -----------------------------------------------------
-    def _contains_many(self, sids: np.ndarray) -> np.ndarray:
-        """Vectorized membership test (the batched twin of ``lookup``)."""
+    def _slots(self, sids: np.ndarray) -> np.ndarray:
+        """Slot of each key, -1 where it is absent: ``lookup``'s probe,
+        one numpy pass per probe step."""
+        out = np.full(sids.shape, -1, np.int64)
+        if sids.size == 0 or self.count == 0:
+            return out
         slot = slot_hash(*split64(sids), self.size)
         mask = self.size - 1
-        found = np.zeros(sids.shape, bool)
         active = np.ones(sids.shape, bool)
         for _ in range(self.max_probe):
             k = self.keys[slot]
             hit = active & (k == sids)
-            found |= hit
+            out[hit] = slot[hit]
             active &= ~hit & (k != EMPTY)
             if not active.any():
                 break
             slot = (slot + 1) & mask
-        return found
+        return out
 
-    def _reserve(self, want_count: int) -> None:
-        size = self.size
-        while want_count > _MAX_LOAD * size:
-            size *= 2
-        if size != self.size:
-            keys, rows = self.items()
-            self._rebuild(keys, rows, size)
+    def _merge_in(self, sids: np.ndarray, rows: np.ndarray) -> None:
+        """Robin Hood insert of absent keys into a table with room. Only
+        the blocks they are homed in are laid out again, with any block
+        those grow into; every other key keeps its slot. A block is a
+        cluster with the empty slot that closes it, so it can be laid out
+        on its own. In a Robin Hood table keys only move away from home
+        here, so ``max_probe`` stays exact as a running maximum (over a
+        first-come table from an older snapshot, an upper bound)."""
+        mask = self.size - 1
+        home = slot_hash(*split64(sids), self.size)
+        lo = (_empty_from(self.keys, (home - 1) & mask, -1) + 1) & mask
+        while True:
+            lo = np.unique(lo)
+            n = (_empty_from(self.keys, lo, 1) - lo) & mask
+            old = (np.repeat(lo - np.cumsum(n) + n, n)
+                   + np.arange(n.sum())) & mask
+            keys = np.concatenate([self.keys[old], sids])
+            slot, disp = _robin_hood(keys, self.size)
+            spilled = slot[(self.keys[slot] != EMPTY) & ~np.isin(slot, old)]
+            if not spilled.size:
+                break
+            # grown into the next block: lay that one out too
+            lo = np.concatenate([lo, (_empty_from(
+                self.keys, (spilled - 1) & mask, -1) + 1) & mask])
+        held = np.concatenate([self.rows[old], rows])
+        self.keys[old], self.rows[old] = EMPTY, -1
+        self.keys[slot], self.rows[slot] = keys, held
+        self.count += int(sids.size)
+        self.max_probe = max(self.max_probe, int(disp.max()) + 1)
 
     def _rebuild(self, keys: np.ndarray, rows: np.ndarray,
                  size: int) -> None:
+        """Lay ``keys`` out afresh at ``size`` slots, or at the smallest
+        power of two above it whose layout probes no further than
+        PROBE_CAP."""
         size = max(_MIN_SIZE, next_pow2(size))
+        while True:
+            slot, disp = _robin_hood(keys, size)
+            max_probe = int(disp.max(initial=0)) + 1
+            if max_probe <= PROBE_CAP:
+                break
+            size *= 2
         self.keys = np.full((size,), EMPTY, np.int64)
         self.rows = np.full((size,), -1, np.int32)
-        self.count = 0
-        self.max_probe = 1
-        if keys.size:
-            self._insert_rounds(keys, rows)
+        self.keys[slot], self.rows[slot] = keys, rows
+        self.count = int(keys.size)
+        self.max_probe = max_probe
 
-    def _insert_rounds(self, sids: np.ndarray, rows: np.ndarray) -> None:
-        """Vectorized linear-probe insertion. Each round places every
-        pending key that (a) found an empty slot and (b) won the
-        first-come tie-break for it; losers advance one slot. Grows and
-        restarts if any key would displace past PROBE_CAP."""
-        mask = self.size - 1
-        slot = slot_hash(*split64(sids), self.size)
-        pending = np.arange(sids.size)
-        for dist in range(PROBE_CAP):
-            k_at = self.keys[slot]
-            dup = k_at == sids[pending]            # key already present
-            if np.any(dup):
-                self.rows[slot[dup]] = rows[pending[dup]]
-                keepm = ~dup
-                pending, slot = pending[keepm], slot[keepm]
-                k_at = k_at[keepm]
-            if pending.size == 0:
-                return
-            empty = k_at == EMPTY
-            # first occurrence wins each contested empty slot this round
-            place = np.zeros(pending.size, bool)
-            if np.any(empty):
-                cand = np.nonzero(empty)[0]
-                _, first = np.unique(slot[cand], return_index=True)
-                place[cand[first]] = True
-                tgt = slot[place]
-                self.keys[tgt] = sids[pending[place]]
-                self.rows[tgt] = rows[pending[place]]
-                self.count += tgt.size
-                self.max_probe = max(self.max_probe, dist + 1)
-            pending, slot = pending[~place], slot[~place]
-            if pending.size == 0:
-                return
-            slot = (slot + 1) & mask
-        # someone would probe past the cap: grow and re-insert the rest
-        # (rebuild re-inserts the already-placed keys at the new size)
-        keys_done, rows_done = self.items()
-        self._rebuild(np.concatenate([keys_done, sids[pending]]),
-                      np.concatenate([rows_done, rows[pending]]),
-                      self.size * 2)
+
+def _empty_from(keys: np.ndarray, slots: np.ndarray, step: int
+                ) -> np.ndarray:
+    """The first empty slot of ``keys`` at or after each of ``slots``
+    (``step`` 1) or at or before it (``step`` -1), wrapping round."""
+    mask = keys.size - 1
+    out = np.empty_like(slots)
+    todo, at, width = np.arange(slots.size), slots.copy(), 16
+    while todo.size:
+        run = (at[todo, None] + step * np.arange(width)) & mask
+        empty = keys[run] == EMPTY
+        hit = empty.any(axis=1)
+        out[todo[hit]] = run[hit, empty[hit].argmax(axis=1)]
+        at[todo] += step * width
+        todo, width = todo[~hit], 2 * width
+    return out
+
+
+def _robin_hood(keys: np.ndarray, size: int
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """(slot, displacement) of each of ``keys`` (distinct, fewer than
+    ``size``) in the Robin Hood layout of a ``size``-slot table. Sorted
+    by (home slot, key), each key takes the first slot at or after its
+    home that the keys before it left free: slot ``i`` of the sort order
+    is ``i + max(home[j] - j for j <= i)``. The last keys of the order
+    may run past the end; they wrap onto slots 0, 1, ... ahead of every
+    key homed there, which can push more keys past the end, so the
+    placement repeats until nothing more wraps (once or twice)."""
+    home = slot_hash(*split64(keys), size)
+    order = np.lexsort((keys, home))
+    h = home[order]
+    i = np.arange(h.size)
+    wrap = 0
+    while True:
+        earliest = np.concatenate([np.zeros(wrap, np.int64),
+                                   h[:h.size - wrap]])
+        pos = i + np.maximum.accumulate(earliest - i)
+        over = int(np.count_nonzero(pos[wrap:] >= size))
+        if not over:
+            break
+        wrap += over
+    slot = np.empty(h.size, np.int64)
+    slot[np.roll(order, wrap)] = pos
+    return slot, (slot - home) & (size - 1)
 
 
 def next_pow2(n: int) -> int:

@@ -131,6 +131,156 @@ def test_table_grow_keeps_probe_bound_at_scale():
 
 
 # ---------------------------------------------------------------------------
+# Robin Hood layout: invariant, order independence, probe bound
+# ---------------------------------------------------------------------------
+def _first_come(keys, size):
+    """Plain sequential linear-probing placement, first come first
+    served: the layout older engines wrote into snapshots. Returns the
+    ``keys`` array of a ``size``-slot table and its longest displacement
+    + 1."""
+    keys = [int(k) for k in keys]
+    homes = routing.slot_hash(*routing.split64(np.asarray(keys, np.int64)),
+                              size).tolist()
+    table, longest = [int(routing.EMPTY)] * size, 0
+    for k, h in zip(keys, homes):
+        d = 0
+        while table[(h + d) % size] != routing.EMPTY:
+            d += 1
+        table[(h + d) % size] = k
+        longest = max(longest, d)
+    return np.asarray(table, np.int64), longest + 1
+
+
+def _displacements(t):
+    """(occupied slots, displacement of each) recomputed from ``t``."""
+    occ = np.flatnonzero(t.keys != routing.EMPTY)
+    home = routing.slot_hash(*routing.split64(t.keys[occ]), t.size)
+    return occ, (occ - home) & (t.size - 1)
+
+
+def _assert_robin_hood(t):
+    """Every cluster in order of home slot, ties by key; no empty slot
+    inside a key's chain; ``max_probe`` exact; ``count`` right."""
+    occ, disp = _displacements(t)
+    assert t.count == occ.size
+    assert t.max_probe == (int(disp.max()) + 1 if occ.size else 1)
+    d = np.full(t.size, -1)
+    d[occ] = disp
+    prev = (occ - 1) & (t.size - 1)
+    # a displaced key's previous slot is taken, by a key at most one
+    # slot further from home (one further: same home, smaller key)
+    assert np.all((disp == 0) | (d[prev] >= 0))
+    assert np.all(disp <= d[prev] + 1)
+    tie = (disp > 0) & (disp == d[prev] + 1)
+    assert np.all(t.keys[prev[tie]] < t.keys[occ[tie]])
+
+
+def _wrapping_ids(size, n, low):
+    """``n`` ids whose home slot in a ``size``-slot table is ``>= low``:
+    their cluster runs past the last slot onto the first ones."""
+    pool = np.arange(50 * size, dtype=np.int64)
+    home = routing.slot_hash(*routing.split64(pool), size)
+    return pool[home >= low][:n]
+
+
+def _ids(n, seed=11):
+    rng = np.random.RandomState(seed)
+    return np.unique(rng.randint(0, 2**63 - 1, n, dtype=np.int64))
+
+
+def _robin_hood_via(path):
+    """A table built through one write path, and the keys it should
+    hold with their rows."""
+    ids = _ids(3000)
+    rows = np.arange(ids.size, dtype=np.int32)
+    t = routing.RouteTable()
+    if path == "bulk":
+        t.insert_many(ids, rows)
+    elif path == "incremental":
+        t.insert_many(ids[:2000], rows[:2000])
+        for lo in range(2000, ids.size, 7):
+            t.insert_many(ids[lo:lo + 7], rows[lo:lo + 7])
+    elif path == "grow":
+        t = routing.RouteTable(64)
+        for lo in range(0, ids.size, 250):    # every call past load grows
+            t.insert_many(ids[lo:lo + 250], rows[lo:lo + 250])
+        assert t.size > 4096
+    elif path == "remove_rows":
+        t.insert_many(ids, rows)
+        t.remove_rows(rows[::3])
+        keep = np.ones(ids.size, bool)
+        keep[::3] = False
+        ids, rows = ids[keep], rows[keep]
+    elif path == "wrap":
+        t = routing.RouteTable(64)
+        ids = _wrapping_ids(64, 40, 56)
+        rows = np.arange(ids.size, dtype=np.int32)
+        for k, r in zip(ids, rows):
+            t.insert(int(k), int(r))
+        assert t.keys[0] != routing.EMPTY and t.keys[-1] != routing.EMPTY
+    return t, ids, rows
+
+
+@pytest.mark.parametrize("path", ["bulk", "incremental", "grow",
+                                  "remove_rows", "wrap"])
+def test_every_write_path_keeps_the_robin_hood_layout(path):
+    t, ids, rows = _robin_hood_via(path)
+    _assert_robin_hood(t)
+    np.testing.assert_array_equal(t.lookup_many(ids), rows)
+    assert all(t.lookup(int(k)) == int(r) for k, r in
+               zip(ids[::97], rows[::97]))
+
+
+@pytest.mark.parametrize("ids", [_ids(4000), _wrapping_ids(64, 44, 50)],
+                         ids=["random", "wrapping"])
+@pytest.mark.parametrize("order", ["one_call", "shuffled_chunks",
+                                   "one_at_a_time"])
+def test_layout_depends_only_on_the_key_set(ids, order):
+    size = 64 if ids.size < 64 else routing._MIN_SIZE
+    rows = (ids % 1000).astype(np.int32)
+    want = routing.RouteTable(size)
+    want.insert_many(ids, rows)
+    t = routing.RouteTable(size)
+    perm = np.random.RandomState(ids.size).permutation(ids.size)
+    if order == "one_call":
+        t.insert_many(ids[perm], rows[perm])
+    elif order == "shuffled_chunks":
+        for chunk in np.array_split(perm, 9):
+            t.insert_many(ids[chunk], rows[chunk])
+    else:
+        for i in perm[:300]:
+            t.insert(int(ids[i]), int(rows[i]))
+        t.insert_many(ids[perm[300:]], rows[perm[300:]])
+    np.testing.assert_array_equal(t.keys, want.keys)
+    np.testing.assert_array_equal(t.rows, want.rows)
+    assert (t.size, t.count, t.max_probe) == \
+        (want.size, want.count, want.max_probe)
+    _assert_robin_hood(t)
+
+
+@pytest.mark.smoke
+def test_stocks5k_ids_probe_at_most_8():
+    """The ids 0..4,999 (one CountMin stack of the 5,000-stock
+    deployment): first-come placement probes up to 30 slots, so the
+    fused programs ran 32 gather passes; the Robin Hood layout of the
+    same table probes at most 8."""
+    ids = np.arange(5000, dtype=np.int64)
+    t = routing.RouteTable()
+    t.insert_many(ids, np.arange(5000, dtype=np.int32))
+    assert t.size == 8192
+    assert t.max_probe <= 8 < _first_come(ids, t.size)[1]
+    assert engine_mod._next_pow2(t.max_probe) == 8
+
+
+def test_probe_bound_no_worse_than_first_come_at_scale():
+    ids = _ids(100_000, seed=12)
+    t = routing.RouteTable()
+    t.insert_many(ids, np.arange(ids.size, dtype=np.int32))
+    assert t.max_probe <= _first_come(ids, t.size)[1]
+    _assert_robin_hood(t)
+
+
+# ---------------------------------------------------------------------------
 # device probe == host table
 # ---------------------------------------------------------------------------
 @pytest.mark.smoke
@@ -151,6 +301,96 @@ def test_route_probe_matches_host_lookup():
         n_probe=engine_mod._next_pow2(t.max_probe)))
     want = np.asarray([t.lookup(int(q)) for q in queries], np.int32)
     np.testing.assert_array_equal(got, want)
+
+
+def _probe(t, queries):
+    lo, hi = routing.split64(t.keys)
+    qlo, qhi = routing.split64(queries)
+    return np.asarray(kops.route_probe(
+        jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(t.rows),
+        jnp.asarray(qlo), jnp.asarray(qhi),
+        n_probe=engine_mod._next_pow2(t.max_probe)))
+
+
+@pytest.mark.parametrize("path", ["bulk", "remove_rows", "wrap"])
+def test_route_probe_matches_host_lookup_on_robin_hood_tables(path):
+    t, ids, rows = _robin_hood_via(path)
+    misses = np.arange(2**40, 2**40 + 64, dtype=np.int64)
+    queries = np.concatenate([ids, misses])
+    want = np.concatenate([rows, np.full(misses.size, -1, np.int32)])
+    np.testing.assert_array_equal(_probe(t, queries), want)
+    np.testing.assert_array_equal(t.lookup_many(queries), want)
+
+
+def _first_come_route(ids, rows, size):
+    """``export_route``-shaped arrays and manifest entry of a first-come
+    table holding ``ids`` -> ``rows``."""
+    keys, max_probe = _first_come(ids, size)
+    row_of = dict(zip(np.asarray(ids).tolist(), np.asarray(rows).tolist()))
+    lo, hi = routing.split64(keys)
+    arrays = dict(keys_lo=lo, keys_hi=hi, rows=np.asarray(
+        [row_of.get(int(k), -1) for k in keys], np.int32))
+    return arrays, dict(size=size, count=len(row_of), max_probe=max_probe)
+
+
+def test_first_come_table_from_an_older_snapshot_still_routes():
+    """Tables written with first-come placement come back through
+    ``import_route`` as they are, and every key is still found by the
+    host lookups and the device probe, before and after new inserts."""
+    from repro.service import migration
+    ids = np.concatenate([_ids(1500, seed=13),
+                          _wrapping_ids(4096, 30, 4080)])
+    rows = np.arange(ids.size, dtype=np.int32)
+    arrays, meta = _first_come_route(ids, rows, 4096)
+    t = migration.import_route(arrays, meta)
+    np.testing.assert_array_equal(t.keys, _first_come(ids, 4096)[0])
+    assert t.max_probe == meta["max_probe"]
+
+    def routes_every_key():
+        assert all(t.lookup(int(k)) == int(r) for k, r in zip(ids, rows))
+        np.testing.assert_array_equal(t.lookup_many(ids), rows)
+        np.testing.assert_array_equal(_probe(t, ids), rows)
+
+    routes_every_key()
+    more = _ids(200, seed=14)
+    t.insert_many(more, np.arange(200, dtype=np.int32) + ids.size)
+    ids = np.concatenate([ids, more])
+    rows = np.arange(ids.size, dtype=np.int32)
+    routes_every_key()
+
+
+def test_restore_routes_a_snapshot_with_a_first_come_table():
+    """A snapshot whose routing table was laid out first come (as older
+    engines wrote them) restores, answers as the live engine does, and
+    keeps routing new ingests."""
+    import json as _json
+    eng, sid_pop, sids = _build_big_id_engine(n_streams=300)
+    table = next(iter(eng.stacks.values())).table
+    keys, rows = table.items()
+    with tempfile.TemporaryDirectory() as d:
+        eng.snapshot(d, 1)
+        step_dir = os.path.join(d, "step-00000001")
+        arrays, meta = _first_come_route(keys, rows, table.size)
+        blob = dict(np.load(os.path.join(step_dir, "leaves.npz")))
+        for name, arr in arrays.items():
+            blob[f"stack0__route__{name}"] = arr
+        np.savez(os.path.join(step_dir, "leaves.npz"), **blob)
+        with open(os.path.join(step_dir, "manifest.json")) as f:
+            man = _json.load(f)
+        man["stacks"][0]["table"] = meta
+        with open(os.path.join(step_dir, "manifest.json"), "w") as f:
+            _json.dump(man, f)
+        eng2 = SDE.restore(d)
+    t2 = next(iter(eng2.stacks.values())).table
+    np.testing.assert_array_equal(t2.keys, _first_come(keys, table.size)[0])
+    np.testing.assert_array_equal(t2.lookup_many(sid_pop),
+                                  table.lookup_many(sid_pop))
+    eng2.ingest(sids, np.ones(len(sids), np.float32))
+    for sid in sid_pop[::37]:
+        q = eng2.handle({"type": "adhoc", "request_id": "q",
+                         "synopsis_id": f"cm/{sid}",
+                         "query": {"items": [int(sid)]}})
+        assert float(q.value[0]) == 2 * float((sids == sid).sum())
 
 
 def test_slot_hash_host_device_lockstep():

@@ -76,6 +76,11 @@ def test_one_tick_of_two_ingests_records_its_phases_nested(tmp_path):
     assert h2d[2] == {"bytes": 17 * 2 * N}
     assert sorted(u[2]["kind"] for u in updates) == ["CountMin",
                                                      "HyperLogLog"]
+    # each update carries its static probe trip count
+    probes = {u[2]["kind"]: u[2]["n_probe"] for u in updates}
+    assert probes == {type(k).__name__: st.n_probe
+                      for k, st in gw.sde.stacks.items()}
+    assert probes["HyperLogLog"] == 1    # a data-source stack routes nothing
     assert tick[0] <= coal[0] <= coal[1] <= ing[0] <= ing[1] <= tick[1]
     assert ing[0] <= h2d[0] <= h2d[1] <= min(u[0] for u in updates)
     assert max(u[1] for u in updates) <= ing[1]
@@ -105,6 +110,19 @@ def test_the_update_program_is_named_by_its_kind(kind):
             ids, np.ones(N, np.float32), np.ones(N, bool))
     text = fn.lower(*args, *(() if src is None else (src,))).as_text()
     assert f"jit_fused_update_{kind}" in text
+
+
+def test_the_route_probe_bound_of_the_stocks5k_deployment():
+    """5,000 per-stream CountMins on ids 0..4,999 and a data-source
+    HyperLogLog: the CountMin update runs 8 probe passes, the HLL's 1."""
+    sde = SDE()
+    sde.handle({"type": "build", "request_id": "b", "synopsis_id": "cm",
+                "kind": "countmin", "params": {"eps": 0.5, "delta": 0.5},
+                "per_stream_of_source": True, "n_streams": 5000})
+    sde.handle({"type": "build", "request_id": "b2", "synopsis_id": "hll",
+                "kind": "hyperloglog", "params": {"rse": 0.05}})
+    assert {type(k).__name__: st.n_probe for k, st in sde.stacks.items()
+            } == {"CountMin": 8, "HyperLogLog": 1}
 
 
 def test_counters_live_in_tracing_only():
