@@ -11,6 +11,9 @@ numbers set the upper readings the configuration's ``limits`` lie under.
 
 * CountMin: a bf16 counter per queried row and item; each acked request
   adds its exact weight, in the order the engine applied the batches.
+* Any other template: the answer of the configuration module's
+  ``control(req, qtype, k, order)`` (``order`` is that order of the acked
+  requests), where the module has one and it gives one, not None.
 * Everything else: the exact answer rounded to bf16. (HyperLogLog
   registers are small integers, exact in bf16, and an estimate rounded
   to bf16 moves by under 0.4 %, inside its bound: the control separates
@@ -36,7 +39,7 @@ from bench.reference.hashing import fold64  # noqa: E402
 BF16 = ml_dtypes.bfloat16
 
 
-def _bf16_running(contribs: np.ndarray) -> np.ndarray:
+def bf16_running(contribs: np.ndarray) -> np.ndarray:
     """Counters kept in bf16, each request's (exact) weight added in
     turn: ``[n_requests, n]`` -> ``[n]``."""
     c = np.zeros(contribs.shape[1], BF16)
@@ -57,15 +60,18 @@ def control_answers(plan, results: dict) -> dict:
             continue
         _, qtype, k = r["kind"].split(":")
         if qtype == "cm_rows":
-            est = _bf16_running(req.row_w[order])
+            est = bf16_running(req.row_w[order])
             other = np.roll(plan.ids[req.qrows], 1)
             same = fold64(other) == req.ids_fold[req.qrows]
             out[rid] = [dict(value=[float(e), float(e) if s else 0.0])
                         for e, s in zip(est, same)]
         elif qtype == "cm_items":
-            out[rid] = _bf16_running(req.item_w[order]).tolist()
+            out[rid] = bf16_running(req.item_w[order]).tolist()
         else:
-            out[rid] = _bf16_round(exact.exact_answer(req, qtype, int(k)))
+            own = plan.hook("control")
+            ans = own(req, qtype, int(k), order) if own else None
+            out[rid] = ans if ans is not None else \
+                _bf16_round(exact.exact_answer(req, qtype, int(k)))
     return out
 
 

@@ -21,7 +21,9 @@ answer of the run against them:
 
 The answers judged are those of the queries sent once the window has
 closed and every acked batch is flushed, so each is held to the exact
-answer over all acked requests.
+answer over all acked requests. A template this module does not know
+goes to the configuration's own module (``plan.module``, from
+``bench/modules/``: its ``exact`` and ``judge``, see ``bench/traffic.py``).
 """
 from __future__ import annotations
 
@@ -66,25 +68,32 @@ class Requests:
                                  for r in self.recs], bool)
         self.ranks: List[np.ndarray] = []
         self.vals: List[np.ndarray] = []
+        self.extra: List[Dict[str, np.ndarray]] = []
         self.users: List[np.ndarray] = []
         self.region: List[np.ndarray] = []
         for r in self.recs:
             if r["kind"] == "ingest":
-                rk, v = plan.ingest_events(r["tag"], r["index"], r["size"])
+                rk, v, extra = plan.ingest_events(r["tag"], r["index"],
+                                                  r["size"])
                 self.ranks.append(rk.astype(np.int32))
-                self.vals.append(v.astype(np.int64))
+                self.vals.append(v)             # in the dtype it was sent
+                self.extra.append(extra)
                 self.users.append(np.zeros(0, np.int64))
                 self.region.append(np.zeros(0, np.int64))
             else:
                 reg, _, users, v = plan.md_records(r["index"], r["size"])
                 self.ranks.append(np.zeros(0, np.int32))
                 self.vals.append(v.astype(np.int64))
+                self.extra.append({})
                 self.users.append(users)
                 self.region.append(reg)
         n = len(self.recs)
         # weights each request adds to the queried per-stream rows, and
-        # to the queried data-source items; its value histogram
+        # to the queried data-source items; its value histogram, where a
+        # GK template reads it (only integer weights under N_VALUES have
+        # one)
         q = plan.cfg.get("queries", {})
+        with_hist = "gk" in q
         self.qrows = plan.query_rows() if "cm_rows" in q else \
             np.zeros(0, np.int64)
         col = np.full(plan.n_streams, -1, np.int64)
@@ -110,13 +119,15 @@ class Requests:
                 self.hot_w[i] = np.bincount(rk[h], v[h],
                                             minlength=self.n_hot)
                 folds = ids_fold[rk]
-                self.hist[i] = np.bincount(v, minlength=N_VALUES)
+                if with_hist:
+                    self.hist[i] = np.bincount(v, minlength=N_VALUES)
                 self.total[i] = v.sum()
             else:
                 folds = fold64(self.users[i])
                 v = v * self.n_levels
-                self.hist[i] = np.bincount(self.vals[i],
-                                           minlength=N_VALUES) * self.n_levels
+                if with_hist:
+                    self.hist[i] = np.bincount(
+                        self.vals[i], minlength=N_VALUES) * self.n_levels
                 self.total[i] = v.sum()
             if item_col:
                 uf, inv = np.unique(folds, return_inverse=True)
@@ -148,6 +159,21 @@ class Requests:
         return int(np.unique(fold64(np.concatenate(us))).size) if us else 0
 
 
+def _hook(plan, name: str, qtype: str):
+    """The configuration module's ``name`` for a template this module
+    does not know."""
+    hook = plan.hook(name)
+    if hook is None:
+        raise ValueError(f"no {name} for query template {qtype!r}")
+    return hook
+
+
+def _params(plan, t: dict) -> dict:
+    """The parameters of the synopsis a built-in template reads."""
+    sid = t["synopsis"]
+    return plan.syn[sid]["params"] if sid in plan.syn else plan.md["params"]
+
+
 def _hll_err(est: float, exact: float, rse: float) -> float:
     return abs(est - exact) / (rse * max(exact, 1.0))
 
@@ -168,10 +194,8 @@ def judge_query(j: Judge, req: Requests, qtype: str, k: int,
     every acked request."""
     plan = req.plan
     t = plan.cfg["queries"][qtype]
-    params = plan.syn[t["synopsis"]]["params"] if t["synopsis"] in plan.syn \
-        else plan.md["params"]
     if qtype == "cm_rows":
-        eps = params["eps"]
+        eps = _params(plan, t)["eps"]
         w = req.row_w.sum(0)
         other = np.roll(plan.ids[req.qrows], 1)
         same = fold64(other) == req.ids_fold[req.qrows]
@@ -181,7 +205,7 @@ def judge_query(j: Judge, req: Requests, qtype: str, k: int,
                 j.put("cm_under", max(0.0, e - est) / max(e, 1.0))
                 j.put("cm_over", max(0.0, est - e) / max(eps * w[i], 1.0))
     elif qtype == "cm_items":
-        eps = params["eps"]
+        eps = _params(plan, t)["eps"]
         n = req.total.sum()
         for est, e in zip(answer, req.item_w.sum(0)):
             j.put("cm_under", max(0.0, e - est) / max(e, 1.0))
@@ -189,13 +213,13 @@ def judge_query(j: Judge, req: Requests, qtype: str, k: int,
     elif qtype in ("hll_total", "subpop_hll", "hll_rows"):
         j.put("hll_err", _hll_err(float(answer),
                                   exact_answer(req, qtype, k),
-                                  params["rse"]))
+                                  _params(plan, t)["rse"]))
     elif qtype == "gk":
         h = req.hist.sum(0)
         for q, x in zip(t["qs"], answer):
-            j.put("gk_err", rank_error(h, x, q) / params["eps"])
+            j.put("gk_err", rank_error(h, x, q) / _params(plan, t)["eps"])
     else:
-        raise ValueError(f"no check for query template {qtype!r}")
+        _hook(plan, "judge", qtype)(j, req, qtype, k, answer)
 
 
 def exact_answer(req: Requests, qtype: str, k: int):
@@ -224,7 +248,7 @@ def exact_answer(req: Requests, qtype: str, k: int):
         rows = plan.hll_rows()
         col = int(np.flatnonzero(req.qrows == rows[k % len(rows)])[0])
         return float(req.row_w[:, col].sum() > 0)
-    raise ValueError(f"no exact answer for query template {qtype!r}")
+    return _hook(plan, "exact", qtype)(req, qtype, k)
 
 
 def judge_continuous(j: Judge, req: Requests, cq: dict) -> None:

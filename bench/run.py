@@ -6,7 +6,9 @@
 
 The cell (``BENCHMARK.json``) names a configuration (``configs/``) and a
 traffic mix (``traffic/``). This process owns the chip: it builds the
-engine, its write-ahead log and checkpointer as ``sde_server.main`` does,
+engine, its write-ahead log and checkpointer as ``sde_server.main`` does
+(with its stacks row-sharded over the devices of the mesh that the
+configuration's ``engine.mesh`` names, where it names one),
 serves it with ``launch.sde_server.serve_socket`` on its own event loop
 thread, and starts the load generator (``loadgen.py``, no JAX) as a
 separate process. The generator sends the builds and warms every shape;
@@ -28,6 +30,7 @@ import asyncio
 import faulthandler
 import importlib.util
 import json
+import math
 import os
 import pathlib
 import shutil
@@ -90,6 +93,23 @@ class Compiles:
         if event == self.EVENT:
             self.count += 1
             self.seconds += duration
+
+
+def engine_mesh(engine: dict, chips: int):
+    """The mesh ``engine["mesh"]`` names (``shape`` and ``axes``) over the
+    cell's first ``chips`` devices, or None where it names none. A mesh
+    of another size than the cell's chips is refused."""
+    spec = engine.get("mesh")
+    if spec is None:
+        return None
+    import jax
+    from repro.launch.mesh import make_mesh as mesh_of
+    size = math.prod(int(n) for n in spec["shape"])
+    if size != chips:
+        raise ValueError(f"the configuration's mesh {spec} holds {size} "
+                         f"devices, the cell asks for {chips} chips")
+    return mesh_of(spec["shape"], spec["axes"],
+                   devices=jax.devices()[:chips])
 
 
 def counters(sde, compiles: Compiles) -> Dict[str, float]:
@@ -206,6 +226,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     # every program goes to the persistent cache, however quick to build
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     device = check(int(cell["chips"]))
+    mesh = engine_mesh(cfg["engine"], int(cell["chips"]))
     compiles = Compiles()
 
     from repro.service import SDE, wal as wal_mod
@@ -219,7 +240,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     run_dir.mkdir(parents=True)
     dur = cfg["durability"]
     sde = SDE(pipelined=bool(cfg["engine"]["pipelined"]), pipeline_depth=2,
-              backend=cfg["engine"]["backend"])
+              backend=cfg["engine"]["backend"], mesh=mesh)
     wal = wal_mod.WriteAheadLog(str(run_dir / "wal.jsonl"), tag=sde.site)
     checkpointer = wal_mod.Checkpointer(
         sde, str(run_dir / "ckpt"), interval=int(dur["checkpoint_interval"]),
@@ -300,7 +321,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     sde.wait_for_snapshot()
     sde.close()
     del sde, checkpointer, server
-    rec = wal_mod.recover(str(run_dir / "ckpt"), str(run_dir / "wal.jsonl"))
+    rec = wal_mod.recover(str(run_dir / "ckpt"), str(run_dir / "wal.jsonl"),
+                          mesh=mesh)
     diff = recovery.image_diff(live, recovery.engine_image(rec))
     rec.close()
     del live, rec

@@ -1,6 +1,7 @@
 """The harness at a tiny size on the CPU with the timed path broken
 underneath: each fault a one-chip cell can have must come out as not
-correct. (The fault of an exchange between chips does not apply: every
+correct, in the price-walk cell through the number of its own module's
+template. (The fault of an exchange between chips does not apply: every
 cell runs on one chip.)"""
 from __future__ import annotations
 
@@ -56,10 +57,14 @@ def _altered_answer(monkeypatch):
     monkeypatch.setattr(SDE, "query_many", query_many)
 
 
-@pytest.mark.parametrize("cell", ["tinystocks.closed", "tiny.closed"])
+@pytest.mark.parametrize("cell", ["tinystocks.closed", "tiny.closed",
+                                  "tinyprice.closed"])
 @pytest.mark.parametrize("fault", [_unchanged, _half_batch, _altered_answer])
 def test_a_broken_timed_path_is_not_correct(tiny, monkeypatch, fault, cell):
     fault(monkeypatch)
     out = harness.run(cell, SEED, 1.0, False, root=tiny,
                       check=testing.cpu_as_chip)
     assert out["correct"] is False, out["compared"]
+    if cell == "tinyprice.closed":
+        gap = out["compared"]["turnover_gap"]
+        assert gap["value"] > gap["limit"], out["compared"]

@@ -7,6 +7,7 @@ import json
 import pathlib
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -25,7 +26,8 @@ def tiny(tmp_path_factory):
 
 
 @pytest.mark.parametrize("config,mix", [("stocks5k_paper", "ingest_closed"),
-                                        ("tiny", "tiny_closed")])
+                                        ("tiny", "tiny_closed"),
+                                        ("tinyprice", "tiny_closed")])
 def test_traffic_is_a_function_of_the_seed_and_never_imports_jax(
         tiny, config, mix):
     code = f"""
@@ -142,6 +144,42 @@ def test_union_and_idle_gaps_of_hand_made_intervals():
         {"wal.sync": 45e-9, "no span": 10e-9})
 
 
+def test_each_of_four_chips_counts_once():
+    """A trace of four ``/device:`` planes, each chip running the same
+    operations: the busy time (so the idle share) is the mean chip's,
+    device seconds of a program are summed over the chips, and a
+    roofline share of the same work is a quarter of one chip's (the
+    work over four chips' worth of device seconds at one chip's
+    peak); idle gaps are the first chip's."""
+    from bench import run as harness
+    chip = dict(ops=[("a", 10.0, 30.0), ("b", 50.0, 60.0)],
+                modules=[("jit_fused_update_CountMin(1)", 10.0, 30.0),
+                         ("jit_fused_update_HyperLogLog(2)", 50.0, 60.0)])
+    four = dict(window=[0.0, 100.0], host=[],
+                device={f"/device:TPU:{i}": json.loads(json.dumps(chip))
+                        for i in range(4)})
+    one = dict(four, device={"/device:TPU:0": chip})
+    assert devtrace.busy_seconds(four) == pytest.approx(30e-9)
+    assert devtrace.busy_seconds(one) == pytest.approx(30e-9)
+    assert devtrace.module_seconds(four, "jit_fused") == pytest.approx(
+        4 * devtrace.module_seconds(one, "jit_fused"))
+    assert devtrace.idle_gaps(four) == devtrace.idle_gaps(one)
+    assert dict(devtrace.top_ops(four))["jit_fused_update_CountMin(1)"] \
+        == pytest.approx(80e-9)
+    cfg = tr.load("configs", "stocks5k_paper")
+    calls = [("sde.ingest", 0.1, 0.2, (16384, False))]
+
+    def read(name, trace):
+        ctx = harness.Context(cfg, (0.0, 1.0), calls, {}, trace,
+                              dict(hbm_bytes_per_s=819e9))
+        return harness.load_reader(testing.REAL / "metrics" /
+                                   f"{name}.py")(ctx)
+    assert read("device.idle_pct.closed", four) == pytest.approx(70.0)
+    assert read("device.idle_pct.closed", one) == pytest.approx(70.0)
+    assert read("update_roofline", four) == pytest.approx(
+        read("update_roofline", one) / 4)
+
+
 def test_roofline_least_bytes_by_hand():
     cfg = tr.load("configs", "stocks5k_paper")
     t = 16384
@@ -228,6 +266,71 @@ def test_the_reference_checks_fail_on_a_wrong_answer(qtype, alter):
                if rec["kind"] == f"q:{qtype}:0")
     res["answers"][rid] = alter(res["answers"][rid])
     assert not _correct(plan, exact.judge_run(plan, res))
+
+
+def test_a_configuration_module_judges_its_own_template(tiny):
+    plan = tr.make_plan("tinyprice", "tiny_closed", 2**31 + 13, 2.0, tiny)
+    ranks, prices, extra = plan.ingest_events(tr.TAG_WINDOW, 0, 256)
+    assert prices.dtype == np.int64 and prices.min() >= 1 and not extra
+    assert np.abs(np.diff(prices)).max() <= 3       # a walk
+    res = _synthetic(plan, 40)
+    assert _correct(plan, exact.judge_run(plan, res))
+    # the module's bf16 control keeps the traded values in bf16
+    assert control.control_numbers(plan, res)["turnover_gap"] > 0
+    rid = next(r for r, rec in res["records"].items()
+               if rec["kind"] == "q:turnover:0")
+    res["answers"][rid][3]["value"][0] += 1
+    assert exact.judge_run(plan, res)["turnover_gap"] == 1.0
+    res["answers"][rid].pop()
+    assert not _correct(plan, exact.judge_run(plan, res))
+
+
+def test_a_module_s_own_control_is_the_control_and_fails(tiny):
+    plan = tr.make_plan("tinyprice", "tiny_closed", 2**31 + 19, 2.0, tiny)
+    res = _synthetic(plan, 40)
+    own = control.control_numbers(plan, res)
+    assert own["turnover_gap"] > plan.cfg["limits"]["turnover_gap"]
+    assert not _correct(plan, own)
+    # the same module without its control: the harness rounds the exact
+    # answer once, which lies nearer than counters kept in bf16
+    bare = types.SimpleNamespace(**{
+        name: getattr(plan.module, name)
+        for name in ("ingest_events", "query", "exact", "judge")})
+    rounded = control.control_numbers(
+        tr.Plan(plan.cfg, plan.mix, plan.seed, plan.seconds, bare), res)
+    assert 0 < rounded["turnover_gap"] < own["turnover_gap"]
+
+
+def test_a_module_s_further_request_fields_are_sent_and_rebuilt():
+    cfg = testing.TINY_CONFIG
+
+    def ingest_events(plan, tag, index, size):
+        ranks = plan.ranks(tag, index, size)
+        return ranks, np.full(size, 0.5), {"items": ranks * 7}
+    plan = tr.Plan(cfg, testing.TINY_MIX, 2**31 + 17, 2.0,
+                   types.SimpleNamespace(ingest_events=ingest_events))
+    line = json.loads(plan.ingest_line(tr.TAG_WINDOW, 2, 16))
+    ranks = plan.ranks(tr.TAG_WINDOW, 2, 16)
+    assert line["stream_ids"] == plan.ids[ranks].tolist()
+    assert line["values"] == [0.5] * 16
+    assert line["items"] == (ranks * 7).tolist()
+    req = exact.Requests(plan, {"i0": dict(
+        kind="ingest", tag=tr.TAG_WINDOW, index=2, size=16, ok=True,
+        batch=1, sent=0.0)})
+    assert req.vals[0].dtype == np.float64
+    assert req.extra[0]["items"].tolist() == line["items"]
+
+
+def test_a_template_no_one_knows_is_refused():
+    plan = _tiny(2**31 + 15)
+    plan.cfg["queries"]["nobody"] = {"synopsis": "cm"}
+    with pytest.raises(ValueError, match="unknown query template"):
+        plan.query("nobody")
+    req = exact.Requests(plan, {})
+    with pytest.raises(ValueError, match="no exact for"):
+        exact.exact_answer(req, "nobody", 0)
+    with pytest.raises(ValueError, match="no judge for"):
+        exact.judge_query(exact.Judge(), req, "nobody", 0, [])
 
 
 def test_the_continuous_checks_fail_on_a_missing_or_wrong_response():

@@ -1,6 +1,6 @@
 """A tiny copy of the benchmark for the CPU tests: the real files, plus
-new configuration, traffic and metric files and a ``BENCHMARK.json`` that
-names them. Nothing that exists is edited."""
+new configuration, traffic, metric and configuration-module files and a
+``BENCHMARK.json`` that names them. Nothing that exists is edited."""
 from __future__ import annotations
 
 import copy
@@ -11,9 +11,14 @@ import shutil
 from bench import traffic as tr
 
 REAL = tr.ROOT
-TINY_CELLS = {"tiny.closed": ("tiny", "tiny_closed"),
-              "tinystocks.closed": ("tinystocks", "tiny_closed")}
+# cell: (configuration, traffic, chips)
+TINY_CELLS = {"tiny.closed": ("tiny", "tiny_closed", 1),
+              "tinystocks.closed": ("tinystocks", "tiny_closed", 1),
+              "tinyprice.closed": ("tinyprice", "tiny_closed", 1),
+              "tinymesh.closed": ("tinymesh", "tiny_closed", 4)}
 DUMMY_METRIC = "dummy.ticks"
+# the configuration module of ``tinyprice``, kept with the test data
+PRICE_MODULE = REAL / "testdata" / "tinyprice.py"
 
 # Every kind and query template the generator and the reference know, at
 # a size the CPU runs in seconds: Zipf-popular hashed stream ids,
@@ -69,6 +74,34 @@ def with_gk(cfg: dict) -> dict:
     return cfg
 
 
+# A deployment whose events and one query template come from its own
+# module (``testdata/tinyprice.py``): each request is a walk of integer
+# prices in cents, and ``turnover`` asks the per-stream CountMin of the
+# first ``rows`` stocks for the traded value of its own id. Its sums stay
+# far under 2**24, so the program's f32 counters hold them exactly.
+PRICE_CONFIG = {
+    "streams": {"count": 256, "ids": "index", "popularity": "round_robin"},
+    "values": {"dist": "price_walk", "start_low": 900, "start_high": 1100,
+               "max_step": 3},
+    "synopses": [
+        {"id": "cm", "kind": "countmin", "params": {"eps": 0.002,
+                                                    "delta": 0.01},
+         "per_stream": "all"},
+        {"id": "src_hll", "kind": "hyperloglog", "params": {"rse": 0.03}}],
+    "queries": {"turnover": {"synopsis": "cm", "rows": 16},
+                "hll_total": {"synopsis": "src_hll"}},
+    "engine": {"pipelined": False, "backend": "xla"},
+    "durability": TINY_CONFIG["durability"],
+    "server": TINY_CONFIG["server"],
+    "limits": {"turnover_gap": 0, "hll_err": 4.0, "lost": 0,
+               "recovered_diff": 0},
+}
+
+# ``tiny`` with its stacks row-sharded over four devices
+MESH_CONFIG = dict(copy.deepcopy(TINY_CONFIG), engine=dict(
+    TINY_CONFIG["engine"], mesh={"shape": [4, 1], "axes": ["data", "model"]}))
+
+
 TINY_MIX = {"ingest": {"connections": 2, "outstanding": 2, "events": 256,
                        "pool": 16},
             "multidim": {"events_per_record": 64, "records": 64},
@@ -92,14 +125,20 @@ def make_root(tmp: pathlib.Path) -> pathlib.Path:
     stocks["queries"]["cm_rows"]["rows"] = 64
     add("configs", "tiny", TINY_CONFIG)
     add("configs", "tinystocks", stocks)
+    add("configs", "tinyprice", PRICE_CONFIG)
+    add("configs", "tinymesh", MESH_CONFIG)
     add("traffic", "tiny_closed", TINY_MIX)
+    module = root / "modules" / "tinyprice.py"
+    assert not module.exists()
+    module.parent.mkdir(exist_ok=True)
+    shutil.copy(PRICE_MODULE, module)
     (root / "metrics" / f"{DUMMY_METRIC}.py").write_text(
         "def read(ctx):\n    return float(ctx.counters['ticks'])\n")
 
     bench = json.loads((REAL.parent / "BENCHMARK.json").read_text())
-    bench["workloads"] = [dict(name=n, config=c, traffic=t, chips=1,
+    bench["workloads"] = [dict(name=n, config=c, traffic=t, chips=chips,
                                why="tiny CPU test cell")
-                          for n, (c, t) in TINY_CELLS.items()]
+                          for n, (c, t, chips) in TINY_CELLS.items()]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
             m["workloads"] = list(TINY_CELLS)

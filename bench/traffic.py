@@ -12,11 +12,33 @@ streams, synopses, the multidim family and its query templates. A traffic
 mix (``traffic/<name>.json``) says how its closed-loop clients drive
 it: request sizes, connections, requests in flight and the warm-up. Both
 are data; this module is the one generator that reads them.
+
+A configuration may also bring a module of its own,
+``modules/<name>.py`` (numpy only: the load generator imports it too),
+for what the built-in vocabulary cannot say. Each of these is optional;
+where the module leaves one out, the built-in path runs unchanged:
+
+* ``ingest_events(plan, tag, index, size)`` - the ranks and values of one
+  ingest request, in any dtype, and optionally a third item: a dict of
+  further request fields, each an array of one entry per event;
+* ``query(plan, qtype, j)`` - the ``j``-th request of a query template
+  the built-in ``Plan.query`` does not know;
+* ``exact(req, qtype, k)`` and ``judge(j, req, qtype, k, answer)`` - the
+  exact answer of such a template and the numbers it is judged by
+  (``reference/exact.py`` calls them; their limits live in the
+  configuration's ``limits``);
+* ``control(req, qtype, k, order)`` - a template's answer computed one
+  precision lower, the acked requests taken in the engine's ``order``
+  (``control.py`` calls it for every template but the CountMin ones;
+  where it is missing or gives None, the control rounds the exact
+  answer once).
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import pathlib
+from types import ModuleType
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -35,6 +57,20 @@ def load(kind: str, name: str, root: pathlib.Path = ROOT) -> dict:
     return json.loads(path.read_text())
 
 
+def load_module(name: str, root: pathlib.Path = ROOT
+                ) -> Optional[ModuleType]:
+    """The configuration's own module, ``modules/<name>.py``, or None
+    where it brings none."""
+    path = root / "modules" / f"{name}.py"
+    if not path.is_file():
+        return None
+    spec = importlib.util.spec_from_file_location(
+        f"bench_module_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def _rng(seed: int, *key: int) -> np.random.Generator:
     return np.random.default_rng([int(seed) % (1 << 64), *key])
 
@@ -42,8 +78,10 @@ def _rng(seed: int, *key: int) -> np.random.Generator:
 class Plan:
     """Everything a run sends, as a function of (config, mix, seed)."""
 
-    def __init__(self, cfg: dict, mix: dict, seed: int, seconds: float):
+    def __init__(self, cfg: dict, mix: dict, seed: int, seconds: float,
+                 module: Optional[ModuleType] = None):
         self.cfg, self.mix, self.seed = cfg, mix, int(seed)
+        self.module = module
         self.seconds = float(seconds)
         st = cfg["streams"]
         self.n_streams = n = int(st["count"])
@@ -84,9 +122,20 @@ class Plan:
         start = int(rng.integers(self.n_streams))
         return self.order[(start + np.arange(size)) % self.n_streams]
 
+    def hook(self, name: str):
+        """The configuration module's ``name``, or None."""
+        return getattr(self.module, name, None)
+
     def ingest_events(self, tag: int, index: int, size: int
-                      ) -> Tuple[np.ndarray, np.ndarray]:
-        """(ranks, integer weights) of one ingest request."""
+                      ) -> Tuple[np.ndarray, np.ndarray, Dict[str,
+                                                            np.ndarray]]:
+        """(ranks, weights, further request fields) of one ingest
+        request; the built-in path gives integer weights and no further
+        field."""
+        hook = self.hook("ingest_events")
+        if hook is not None:
+            ranks, vals, *extra = hook(self, tag, index, size)
+            return ranks, vals, (extra[0] if extra else {})
         ranks = self.ranks(tag, index, size)
         v = self.values
         if v["dist"] == "unit":
@@ -94,7 +143,7 @@ class Plan:
         else:                                    # "uniform_int"
             vals = _rng(self.seed, tag, index, 1).integers(
                 int(v["low"]), int(v["high"]) + 1, size)
-        return ranks, vals
+        return ranks, vals, {}
 
     def md_records(self, index: int, size: int):
         """(region index, device index, user id, weight) per record."""
@@ -112,10 +161,12 @@ class Plan:
     # -- request lines -----------------------------------------------------
     def ingest_line(self, tag: int, index: int, size: int) -> bytes:
         """One ingest request, encoded, without its request id."""
-        ranks, vals = self.ingest_events(tag, index, size)
+        ranks, vals, extra = self.ingest_events(tag, index, size)
         sids = ",".join(map(str, self.ids[ranks].tolist()))
+        more = "".join(f',"{k}":[{",".join(map(str, a.tolist()))}]'
+                       for k, a in extra.items())
         return (f'{{"type":"ingest","stream_ids":[{sids}],"values":'
-                f'[{",".join(map(str, vals.tolist()))}]}}\n').encode()
+                f'[{",".join(map(str, vals.tolist()))}]{more}}}\n').encode()
 
     def md_line(self, index: int, size: int) -> bytes:
         region, device, users, vals = self.md_records(index, size)
@@ -160,7 +211,7 @@ class Plan:
     def query(self, qtype: str, j: int = 0) -> dict:
         """The ``j``-th request of one query template."""
         t = self.cfg["queries"][qtype]
-        sid = t["synopsis"]
+        sid = t.get("synopsis")
         if qtype == "cm_rows":
             rows = self.ids[self.query_rows()]
             other = np.roll(rows, 1)
@@ -184,6 +235,9 @@ class Plan:
             rows = self.hll_rows()
             return dict(type="adhoc",
                         synopsis_id=f"{sid}/{int(self.ids[rows[j % len(rows)]])}")
+        hook = self.hook("query")
+        if hook is not None:
+            return hook(self, qtype, j)
         raise ValueError(f"unknown query template {qtype!r}")
 
     def hll_rows(self) -> np.ndarray:
@@ -206,7 +260,7 @@ def make_plan(config: str, traffic: str, seed: int, seconds: float,
               root: Optional[pathlib.Path] = None) -> Plan:
     root = ROOT if root is None else root
     return Plan(load("configs", config, root), load("traffic", traffic, root),
-                seed, seconds)
+                seed, seconds, load_module(config, root))
 
 
 def cell(name: str, root: Optional[pathlib.Path] = None) -> Dict[str, str]:
